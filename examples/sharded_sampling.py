@@ -1,28 +1,30 @@
 """Sharded full-catalog sampling across a device mesh — runnable walkthrough.
 
-Simulates a 2-device CPU mesh (``--xla_force_host_platform_device_count``,
-set below *before* jax initializes), shards one NDPP kernel's item axis
+Builds a mesh over every local device, shards one NDPP kernel's item axis
 across it, and draws samples with all three backends:
 
   * speculative batched rejection (``sample_batched_many(mesh=...)``),
   * MCMC up/down chains (``run_chains_sharded``),
   * the slot-pool ``SamplerEngine`` with ``mesh=`` (rejection + MCMC ticks).
 
-Every sharded draw is bit-identical to its single-device counterpart —
-the mesh changes where the (M, R) rows live, never what is sampled; the
-script asserts this for each backend and prints the per-device bytes of
-the sharded proposal tree.
+Every sharded draw is bit-identical to its single-device counterpart
+that runs the same XLA arithmetic — the mesh changes where the (M, R)
+rows live, never what is sampled; the script asserts this for each
+backend and prints the per-device bytes of the sharded proposal tree.
+On a TPU the one-device rejection descent is the Pallas kernel, which
+sums <Q, node> in another order than the sharded XLA descent, so there
+the rejection comparison is reported, not asserted.
+
+On a host without accelerators, simulate devices through the environment:
+
+    XLA_FLAGS=--xla_force_host_platform_device_count=2 JAX_PLATFORMS=cpu \\
+        PYTHONPATH=src python examples/sharded_sampling.py
 """
-import os
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=2")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import jax                                                  # noqa: E402
-import jax.numpy as jnp                                     # noqa: E402
-import numpy as np                                          # noqa: E402
-
-from repro.core import (                                    # noqa: E402
+from repro.core import (
     init_empty,
     preprocess,
     run_chains,
@@ -30,8 +32,9 @@ from repro.core import (                                    # noqa: E402
     sample_batched_many,
     shard_sampler,
 )
-from repro.launch.mesh import make_sampler_mesh             # noqa: E402
-from repro.serve.sampler_engine import (                    # noqa: E402
+from repro.kernels.backend import on_tpu
+from repro.launch.mesh import make_sampler_mesh
+from repro.serve.sampler_engine import (
     SampleRequest,
     SamplerEngine,
 )
@@ -66,11 +69,13 @@ def main():
     key = jax.random.PRNGKey(0)
     res = sample_batched_many(sharded, key, 32, n_spec=4, mesh=mesh)
     ref = sample_batched_many(sampler, key, 32, n_spec=4)
-    assert np.array_equal(np.asarray(res.items), np.asarray(ref.items))
+    same = np.array_equal(np.asarray(res.items), np.asarray(ref.items))
+    assert same or on_tpu()
     sizes = np.asarray(res.mask).sum(1)
     print(f"rejection: 32 draws, mean |Y| = {sizes.mean():.2f}, "
           f"mean trials = {float(np.asarray(res.trials).mean()):.2f} "
-          f"(bit-identical to single-device)")
+          f"({'bit-identical to' if same else 'differs from'} "
+          f"single-device)")
 
     # 2) MCMC up/down chains, catalog rows device-local
     n_chains, n_steps = 4, 128

@@ -42,7 +42,7 @@ TRACING_WRAPPERS = {
     "jax.lax.switch",
     "jax.lax.map",
     "jax.lax.associative_scan",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
     "jax.experimental.pallas.pallas_call",
 }
 

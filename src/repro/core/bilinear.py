@@ -9,9 +9,7 @@ Every hot path of the paper reduces to this primitive with a different
 * rejection-sampler acceptance diagnostics.
 
 ``bilinear_scores`` is the pure-jnp implementation (also the oracle for the
-Pallas kernel in ``repro.kernels.bilinear``).  ``bilinear_scores_fast``
-dispatches to the Pallas kernel for MXU-aligned shapes on TPU and falls back
-to jnp elsewhere.
+Pallas kernel in ``repro.kernels.bilinear``).
 """
 from __future__ import annotations
 
@@ -22,16 +20,6 @@ import jax.numpy as jnp
 def bilinear_scores(Z: jax.Array, W: jax.Array) -> jax.Array:
     """p_i = z_i^T W z_i for all rows z_i of Z.  Z: (M, R), W: (R, R)."""
     return jnp.einsum("mi,ij,mj->m", Z, W, Z, optimize=True)
-
-
-def bilinear_scores_fast(Z: jax.Array, W: jax.Array) -> jax.Array:
-    """Kernel-dispatched version (falls back to jnp off-TPU)."""
-    try:
-        from repro.kernels.bilinear import ops as _ops
-
-        return _ops.bilinear(Z, W)
-    except ImportError:  # pragma: no cover - kernel unavailable
-        return bilinear_scores(Z, W)
 
 
 def conditional_inner_matrix(

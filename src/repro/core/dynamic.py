@@ -41,7 +41,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .rejection import (
@@ -229,9 +228,9 @@ def _spec_round_dual_sharded_impl(prop: DualProposal, live_sp: SpectralNDPP,
             accept = jnp.log(u) <= log_ratio
         return items, mask, accept
 
-    f = shard_map(inner, mesh=mesh,
-                  in_specs=(prop_specs, live_specs, P(None)),
-                  out_specs=(P(None),) * 3, check_rep=False)
+    f = jax.shard_map(inner, mesh=mesh,
+                      in_specs=(prop_specs, live_specs, P(None)),
+                      out_specs=(P(None),) * 3, check_vma=False)
     return f(prop, live_sp, keys)
 
 

@@ -41,10 +41,12 @@ def _basket_logdets(
     """log det(L_{Y_i} + eps I) for each padded basket (unit padding diag)."""
     vy = V[baskets.items] * baskets.mask[..., None]      # (n, k, K)
     by = B[baskets.items] * baskets.mask[..., None]
-    skew = D - D.T
-    ly = jnp.einsum("nik,njk->nij", vy, vy) + jnp.einsum(
-        "nik,kl,njl->nij", by, skew, by
-    )
+    # products summed over K elementwise, not as dots: the backend's dot
+    # picks its summation order from the padded width k, so the same basket
+    # re-padded wider drifted by an ulp per entry (~1e-5 in its log det)
+    bs = jnp.sum(by[..., :, None] * (D - D.T), axis=-2)  # (n, k, K)
+    ly = jnp.sum(vy[:, :, None, :] * vy[:, None, :, :]
+                 + bs[:, :, None, :] * by[:, None, :, :], axis=-1)
     k_pad = ly.shape[-1]
     eye = jnp.eye(k_pad, dtype=ly.dtype)
     # padding rows get diag exactly 1 (factor 1 in the det); the eps jitter
@@ -53,9 +55,41 @@ def _basket_logdets(
     # offset that the variable-basket-size exactness tests catch
     diag_fill = (1.0 - baskets.mask)[..., None] * eye[None]
     ly = ly + diag_fill + _DET_EPS * baskets.mask[..., None] * eye[None]
-    sign, logdet = jnp.linalg.slogdet(ly)
+    sign, logdet = _slogdet_width_invariant(ly)
     # det should be positive for PSD-style kernels; clamp invalid to -inf-ish
     return jnp.where(sign > 0, logdet, -1e9)
+
+
+@jax.jit
+def _slogdet_width_invariant(a: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """slogdet of a batch (n, k, k) by Gaussian elimination with partial
+    pivoting. Every update is elementwise and log|pivot| is summed column by
+    column, so a basket padded with identity rows/columns gets the same
+    result at any k (LAPACK's LU splits its recursion by k, which moved a
+    re-padded log det by up to ~1e-5)."""
+    k = a.shape[-1]
+    idx = jnp.arange(k, dtype=jnp.int32)
+
+    def step(j, carry):
+        a, sign, logdet = carry
+        col = jnp.abs(a[:, :, j])
+        p = jnp.argmax(jnp.where(idx >= j, col, -1.0), axis=-1)   # (n,)
+        row_j = a[:, j, :]
+        row_p = jnp.take_along_axis(a, p[:, None, None], axis=1)[:, 0]
+        is_j = (idx == j)[None, :, None]
+        is_p = (idx[None, :] == p[:, None])[..., None]
+        a = jnp.where(is_j, row_p[:, None], jnp.where(is_p, row_j[:, None], a))
+        piv = a[:, j, j]
+        below = (idx > j)[None, :, None] & (idx > j)[None, None, :]
+        lower = a[:, :, j] / piv[:, None]
+        a = jnp.where(below, a - lower[:, :, None] * a[:, j, None, :], a)
+        sign = sign * jnp.sign(piv) * jnp.where(p == j, 1.0, -1.0)
+        return a, sign, logdet + jnp.log(jnp.abs(piv))
+
+    n = a.shape[0]
+    init = (a, jnp.ones((n,), a.dtype), jnp.zeros((n,), a.dtype))
+    _, sign, logdet = jax.lax.fori_loop(0, k, step, init)
+    return sign, logdet
 
 
 def log_normalizer(V: jax.Array, B: jax.Array, D: jax.Array) -> jax.Array:

@@ -48,7 +48,6 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .types import SpectralNDPP
@@ -426,24 +425,32 @@ def run_chains_sharded(sp: SpectralNDPP, chain_keys: jax.Array,
                     axis_name="model", m_total=m_total)
             )(ck, st)
 
-    f = shard_map(inner, mesh=mesh, in_specs=(sp_specs, P(None), P(None)),
-                  out_specs=P(None), check_rep=False)
+    f = jax.shard_map(inner, mesh=mesh, in_specs=(sp_specs, P(None), P(None)),
+                      out_specs=P(None), check_vma=False)
     return f(sp, chain_keys, states)
 
 
 # --------------------------------------------------------------- greedy init
 
 
-@functools.partial(jax.jit, static_argnames=("force_interpret",))
+@functools.partial(jax.jit, static_argnames=("mesh", "force_interpret"))
 def _greedy_round(sp: SpectralNDPP, states: MCMCState, chain_keys: jax.Array,
-                  round_idx: jax.Array, *, force_interpret: bool = False):
+                  round_idx: jax.Array, *, mesh: Optional[Mesh] = None,
+                  force_interpret: bool = False):
     """One greedy round: score EVERY candidate for EVERY chain in one fused
-    all-candidate pass and add one item per chain ~ its determinant gain."""
+    all-candidate pass and add one item per chain ~ its determinant gain.
+    ``mesh``: the rows of ``sp.Z`` are sharded over its "model" axis, and
+    each shard scores its own rows (XLA cannot partition a Pallas kernel
+    itself)."""
     from repro.kernels.mcmc_score import ops as mops
 
     x = sp.x_matrix()
     a = jax.vmap(lambda st: score_matrix(sp, st))(states)  # (C, 2K, 2K)
-    scores = mops.score_all(sp.Z, a, force_interpret=force_interpret)
+    if mesh is None:
+        scores = mops.score_all(sp.Z, a, force_interpret=force_interpret)
+    else:
+        scores = mops.score_all_sharded(sp.Z, a, mesh,
+                                        force_interpret=force_interpret)
     taken = jax.vmap(
         lambda st: (jnp.arange(sp.M, dtype=jnp.int32)[None, :] ==
                     jnp.where(st.mask, st.items, -1)[:, None]).any(0)
@@ -464,7 +471,8 @@ def _greedy_round(sp: SpectralNDPP, states: MCMCState, chain_keys: jax.Array,
 
 
 def init_greedy(sp: SpectralNDPP, key: jax.Array, n_chains: int, k: int,
-                *, force_interpret: bool = False) -> MCMCState:
+                *, mesh: Optional[Mesh] = None,
+                force_interpret: bool = False) -> MCMCState:
     """Stochastic-greedy size-k initial states for C = ``n_chains`` chains.
 
     Returns an ``MCMCState`` with leading dim C (items/mask (C, R), minv
@@ -477,13 +485,15 @@ def init_greedy(sp: SpectralNDPP, key: jax.Array, n_chains: int, k: int,
     instead of a C x M python loop) and samples an item per chain with
     probability proportional to its positive determinant gain.  Used as the
     k-NDPP chain initializer: starting states have det(L_Y) > 0 and are
-    spread across high-mass subsets, which shortens burn-in.
+    spread across high-mass subsets, which shortens burn-in.  ``mesh``:
+    the catalog rows are sharded over its "model" axis (the starts are
+    bit-identical to the unsharded ones).
     """
     states = jax.vmap(lambda _: init_empty(sp))(jnp.arange(n_chains, dtype=jnp.int32))
     chain_keys = jax.random.split(key, n_chains)
     for i in range(k):
         states = _greedy_round(sp, states, chain_keys,
-                               jnp.asarray(i, jnp.int32),
+                               jnp.asarray(i, jnp.int32), mesh=mesh,
                                force_interpret=force_interpret)
     return jax.vmap(lambda st: refresh(sp, st))(states)
 
@@ -527,7 +537,7 @@ def sample_mcmc(
         states = jax.vmap(lambda _: init_empty(sp))(jnp.arange(n_chains, dtype=jnp.int32))
     else:
         states = init_greedy(sp, jax.random.fold_in(key, 0x6d636d63),
-                             n_chains, k)
+                             n_chains, k, mesh=mesh)
     if mesh is None:
         _, items_tr, mask_tr, acc_tr = run_chains(
             sp, chain_keys, states, n_steps=n_steps, fixed=k is not None,

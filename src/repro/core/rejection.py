@@ -19,7 +19,6 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .types import SpectralNDPP
@@ -288,8 +287,8 @@ def _spec_round_sharded_impl(sampler: NDPPSampler, keys: jax.Array,
             accept = jnp.log(u) <= log_ratio
         return items, mask, accept
 
-    f = shard_map(inner, mesh=mesh, in_specs=in_specs,
-                  out_specs=(P(None),) * 3, check_rep=False)
+    f = jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                      out_specs=(P(None),) * 3, check_vma=False)
     return f(sampler, keys)
 
 

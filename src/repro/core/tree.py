@@ -23,8 +23,11 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from repro.kernels.bilinear import ops as bilinear_ops
+from repro.kernels.spec_round import ops as spec_round_ops
+from repro.kernels.tree_sum import ops as tree_sum_ops
 
 from .types import SpectralNDPP
 
@@ -95,18 +98,11 @@ def construct_tree(lam: jax.Array, W: jax.Array, block: int = 64) -> SampleTree:
     Uses the blocked outer-product reduction (``repro.kernels.tree_sum`` on
     TPU; jnp einsum otherwise) for the leaf level, then pairwise sums.
     """
-    m, r = W.shape
+    m = W.shape[0]
     n_blocks = max(1, 2 ** math.ceil(math.log2(max(1, math.ceil(m / block)))))
     m_pad = n_blocks * block
     wp = jnp.pad(W, ((0, m_pad - m), (0, 0)))
-    try:
-        from repro.kernels.tree_sum import ops as _ops
-
-        leaf = _ops.block_outer_sums(wp, block)
-    except ImportError:  # pragma: no cover - kernel package unavailable
-        leaf = jnp.einsum("nbi,nbj->nij", wp.reshape(n_blocks, block, r),
-                          wp.reshape(n_blocks, block, r))
-    levels = [leaf]
+    levels = [tree_sum_ops.block_outer_sums(wp, block)]
     while levels[-1].shape[0] > 1:
         cur = levels[-1]
         levels.append(cur[0::2] + cur[1::2])
@@ -140,27 +136,8 @@ def update_rows(tree: SampleTree, idx: jax.Array, rows: jax.Array,
     updated W.  ``lam`` optionally replaces the stored eigenvalues (the
     dual refresh path of ``core.dynamic``).
     """
-    try:
-        from repro.kernels.tree_sum import ops as _ops
-
-        levels, w_new = _ops.tree_update(tree.levels, tree.W, idx, rows,
-                                         tree.block)
-    except ImportError:  # pragma: no cover - kernel package unavailable
-        w_new = tree.W.at[idx].set(rows)
-        blks = (idx // tree.block).astype(jnp.int32)
-        gathered = w_new[blks[:, None] * tree.block
-                         + jnp.arange(tree.block, dtype=jnp.int32)[None, :]]
-        grams = jnp.einsum("nbi,nbj->nij", gathered.astype(jnp.float32),
-                           gathered.astype(jnp.float32))
-        levels = [tree.levels[-1].at[blks].set(
-            grams.astype(tree.levels[-1].dtype))]
-        nodes = blks
-        for lvl in range(tree.depth - 1, -1, -1):
-            nodes = nodes // 2
-            child = levels[0]
-            levels.insert(0, tree.levels[lvl].at[nodes].set(
-                child[2 * nodes] + child[2 * nodes + 1]))
-        levels = tuple(levels)
+    levels, w_new = tree_sum_ops.tree_update(tree.levels, tree.W, idx, rows,
+                                             tree.block)
     return SampleTree(W=w_new, lam=tree.lam if lam is None else lam,
                       levels=tuple(levels), block=tree.block, M=tree.M)
 
@@ -179,8 +156,6 @@ def _update_rows_local(
     maintained tree stays bit-equal to the plain ``update_rows`` result (and
     hence to a from-scratch ``construct_tree``).
     """
-    from repro.kernels.tree_sum import ops as _ops
-
     block, depth = tree.block, tree.depth
     n_blocks_global = m_pad_global // block
     shard = jax.lax.axis_index(axis_name)
@@ -197,12 +172,12 @@ def _update_rows_local(
         bps = rps // block
         own_blk = (blks >= shard * bps) & (blks < (shard + 1) * bps)
         loc_blk = jnp.clip(blks - shard * bps, 0, bps - 1)
-        g_loc = _ops.gathered_block_grams(w_loc, loc_blk, block)
+        g_loc = tree_sum_ops.gathered_block_grams(w_loc, loc_blk, block)
         vals = jax.lax.psum(
             jnp.where(own_blk[:, None, None], g_loc, 0.0), axis_name)
     else:
         w_loc = w_loc.at[idx].set(rows)
-        vals = _ops.gathered_block_grams(w_loc, blks, block)
+        vals = tree_sum_ops.gathered_block_grams(w_loc, blks, block)
     vals = vals.astype(tree.levels[-1].dtype)
 
     # walk leaf -> root carrying the *replicated* recomputed node values;
@@ -256,8 +231,8 @@ def update_rows_sharded(
         return _update_rows_local(tree_loc, idx, rows, axis_name="model",
                                   m_pad_global=m_pad)
 
-    f = shard_map(inner, mesh=mesh, in_specs=(specs, P(None), P(None)),
-                  out_specs=specs, check_rep=False)
+    f = jax.shard_map(inner, mesh=mesh, in_specs=(specs, P(None), P(None)),
+                      out_specs=specs, check_vma=False)
     return f(tree, idx, rows)
 
 
@@ -356,18 +331,6 @@ def sample_proposal_dpp(
 # --------------------------------------------------------------------------
 
 
-def _leaf_scores_batch(w_blk: jax.Array, q: jax.Array) -> jax.Array:
-    """Leaf scores for N proposals at once: (N, block, R) x (N, R, R) ->
-    (N, block) via the fused bilinear kernel (Pallas on TPU, einsum ref
-    elsewhere)."""
-    try:
-        from repro.kernels.bilinear import ops as _ops
-
-        return _ops.bilinear_batched(w_blk, q)
-    except ImportError:  # pragma: no cover - kernel package unavailable
-        return jnp.einsum("nbi,nij,nbj->nb", w_blk, q, w_blk, optimize=True)
-
-
 def _gather_row(W: jax.Array, j: jax.Array,
                 axis_name: Optional[str]) -> jax.Array:
     """Row fetch via the shared masked-psum gather (plain when axis None)."""
@@ -444,25 +407,6 @@ def _descend_batch(
     return idx
 
 
-def _descend_score_fused(
-    tree: SampleTree, q: jax.Array, us: jax.Array,
-) -> Tuple[jax.Array, jax.Array]:
-    """Fused descent + leaf scoring for the unsharded hot path: one
-    kernel dispatch on TPU (``kernels.spec_round``), the bit-identical
-    jnp oracle elsewhere.  Returns (block ids (N,), raw *unclamped*
-    scores (N, block)); the caller owns the clamp and the categorical
-    draw so the PRNG stream stays outside the kernel."""
-    try:
-        from repro.kernels.spec_round import ops as _ops
-
-        return _ops.descend_score(tree.levels, tree.W, tree.block, q, us)
-    except ImportError:  # pragma: no cover - kernel package unavailable
-        blk = _descend_batch(tree, q, us)
-        blk_ar = jnp.arange(tree.block, dtype=jnp.int32)
-        rows = blk[:, None] * tree.block + blk_ar[None, :]
-        return blk, _leaf_scores_batch(tree.W[rows], q)
-
-
 def sample_elementary_batch(
     tree: SampleTree, e_masks: jax.Array, keys: jax.Array, *,
     axis_name: Optional[str] = None, m_pad_global: Optional[int] = None,
@@ -503,6 +447,9 @@ def sample_elementary_batch(
     w_sharded = (axis_name is not None and m_pad_global is not None
                  and w_rows != m_pad_global)
     shard = None if axis_name is None else jax.lax.axis_index(axis_name)
+    # the kernel's node layout is built once here, not once per item step
+    flat_levels = (spec_round_ops.descent_operands(tree.levels)
+                   if axis_name is None else None)
 
     def cond(state):
         t, _, _ = state
@@ -518,36 +465,31 @@ def sample_elementary_batch(
         # named scopes are compile-time HLO metadata (free at runtime);
         # names come from the repro.obs.prof.phases catalog — core stays
         # import-free of repro.obs
-        if axis_name is None:
-            # unsharded hot path: descent + leaf scoring fuse into one
-            # kernel (the spec_round dispatcher applies the ndpp.* scopes)
-            blk, raw = _descend_score_fused(tree, q, us)
-            with jax.named_scope("ndpp.leaf_scoring"):
-                scores = jnp.maximum(raw, 0.0)
-                j_local = jax.vmap(jax.random.categorical)(
-                    kk[:, 1], jnp.log(scores + 1e-30)
-                )
-        else:
-            with jax.named_scope("ndpp.tree_descent"):
-                blk = _descend_batch(tree, q, us, axis_name=axis_name)  # (N,)
-            with jax.named_scope("ndpp.leaf_scoring"):
-                if not w_sharded:
-                    rows = blk[:, None] * tree.block + blk_ar[None, :]
-                    w_blk = tree.W[rows]                        # (N, block, R)
-                    scores = jnp.maximum(_leaf_scores_batch(w_blk, q), 0.0)
-                else:
-                    bps = w_rows // tree.block         # blocks per shard
-                    base_blk = shard * bps
-                    own = (blk >= base_blk) & (blk < base_blk + bps)
-                    loc = jnp.clip(blk - base_blk, 0, bps - 1)
-                    rows = loc[:, None] * tree.block + blk_ar[None, :]
-                    w_blk = tree.W[rows]
-                    raw = jnp.where(own[:, None],
-                                    _leaf_scores_batch(w_blk, q), 0.0)
-                    scores = jnp.maximum(jax.lax.psum(raw, axis_name), 0.0)
-                j_local = jax.vmap(jax.random.categorical)(
-                    kk[:, 1], jnp.log(scores + 1e-30)
-                )
+        with jax.named_scope("ndpp.tree_descent"):
+            if axis_name is None:
+                # unsharded hot path: the spec_round kernel on TPU
+                blk = spec_round_ops.descend(tree.levels, flat_levels, q, us)
+            else:
+                blk = _descend_batch(tree, q, us, axis_name=axis_name)
+        with jax.named_scope("ndpp.leaf_scoring"):
+            if not w_sharded:
+                rows = blk[:, None] * tree.block + blk_ar[None, :]
+                w_blk = tree.W[rows]                            # (N, block, R)
+                scores = jnp.maximum(
+                    bilinear_ops.bilinear_batched(w_blk, q), 0.0)
+            else:
+                bps = w_rows // tree.block             # blocks per shard
+                base_blk = shard * bps
+                own = (blk >= base_blk) & (blk < base_blk + bps)
+                loc = jnp.clip(blk - base_blk, 0, bps - 1)
+                rows = loc[:, None] * tree.block + blk_ar[None, :]
+                w_blk = tree.W[rows]
+                raw = jnp.where(own[:, None],
+                                bilinear_ops.bilinear_batched(w_blk, q), 0.0)
+                scores = jnp.maximum(jax.lax.psum(raw, axis_name), 0.0)
+            j_local = jax.vmap(jax.random.categorical)(
+                kk[:, 1], jnp.log(scores + 1e-30)
+            )
         j = blk * tree.block + j_local
         w_j = _gather_row(tree.W, j,
                           axis_name if w_sharded else None)     # (N, R)
@@ -667,17 +609,17 @@ def sample_proposal_dpp_batch_sharded(
             return sample_proposal_dpp_batch(
                 tree_loc, keys, axis_name="model", m_pad_global=m_pad)
 
-        f = shard_map(inner, mesh=mesh, in_specs=(specs, P(None)),
-                      out_specs=(P(None), P(None)), check_rep=False)
+        f = jax.shard_map(inner, mesh=mesh, in_specs=(specs, P(None)),
+                          out_specs=(P(None), P(None)), check_vma=False)
         return f(tree, keys)
 
     def inner(tree_loc, keys, u):
         return sample_proposal_dpp_batch(
             tree_loc, keys, axis_name="model", m_pad_global=m_pad, dual_u=u)
 
-    f = shard_map(inner, mesh=mesh,
-                  in_specs=(specs, P(None), P(None, None)),
-                  out_specs=(P(None), P(None)), check_rep=False)
+    f = jax.shard_map(inner, mesh=mesh,
+                      in_specs=(specs, P(None), P(None, None)),
+                      out_specs=(P(None), P(None)), check_vma=False)
     return f(tree, keys, dual_u)
 
 
@@ -694,8 +636,8 @@ def sample_elementary_batch_sharded(
         return sample_elementary_batch(
             tree_loc, e_masks, keys, axis_name="model", m_pad_global=m_pad)
 
-    f = shard_map(inner, mesh=mesh, in_specs=(specs, P(None), P(None)),
-                  out_specs=(P(None), P(None)), check_rep=False)
+    f = jax.shard_map(inner, mesh=mesh, in_specs=(specs, P(None), P(None)),
+                      out_specs=(P(None), P(None)), check_vma=False)
     return f(tree, e_masks, keys)
 
 

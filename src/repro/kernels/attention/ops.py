@@ -1,23 +1,14 @@
 """Public attention op: Pallas flash kernel on TPU, jnp oracle elsewhere."""
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from ..backend import interpret_requested, on_tpu
 from .flash import flash_attention_pallas
 from .ref import mha_ref
-
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1"
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover - no backend initialized
-        return False
 
 
 def mha(
@@ -36,11 +27,11 @@ def mha(
     ragged kv_len (decode paths with ragged caches use the oracle, which XLA
     fuses well for q_len == 1).
     """
-    interpret = force_interpret or _INTERPRET
+    interpret = interpret_requested(force_interpret)
     b, h, sq, d = q.shape
     sk = k.shape[2]
     usable = (
-        (_on_tpu() or interpret)
+        (on_tpu() or interpret)
         and kv_len is None
         and sq % 128 == 0
         and sk % 128 == 0

@@ -36,29 +36,33 @@ def _bilinear_batched_kernel(z_ref, w_ref, out_ref):
     z = z_ref[0]              # (B, R)   VMEM, one batch element per program
     w = w_ref[0]              # (R, R)   VMEM, per-element inner matrix
     zw = jnp.dot(z, w, preferred_element_type=jnp.float32)  # MXU
-    out_ref[0] = jnp.sum(zw * z.astype(jnp.float32), axis=1)
+    out_ref[0] = jnp.sum(zw * z.astype(jnp.float32), axis=1)[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bilinear_batched_pallas(
     Z: jax.Array, W: jax.Array, *, interpret: bool = False
 ) -> jax.Array:
-    """Z: (N, B, R), W: (N, R, R) -> (N, B) float32.  B % 8 == 0, R % 128 == 0
-    (ops.py pads).  Grid over N: each program fuses one proposal's
-    (B, R) x (R, R) x (B, R) quadratic form in a single VMEM pass — the
-    speculative leaf-scoring layout (n_spec proposals, per-proposal Q)."""
+    """Z: (N, B, R), W: (N, R, R) -> (N, B) float32, any B and R.  Grid
+    over N: each program fuses one proposal's (B, R) x (R, R) x (B, R)
+    quadratic form in a single VMEM pass — the speculative leaf-scoring
+    layout (n_spec proposals, per-proposal Q).  Every block spans its
+    array's last two dims whole — (B, R), (R, R) and the (1, B) rows of an
+    (N, 1, B) output — which the TPU tiling rule accepts unpadded."""
     n, b, r = Z.shape
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _bilinear_batched_kernel,
         grid=(n,),
         in_specs=[
             pl.BlockSpec((1, b, r), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, r, r), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, b), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, b), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, b), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1, b), jnp.float32),
         interpret=interpret,
+        name="ndpp_bilinear_batched",
     )(Z, W)
+    return out.reshape(n, b)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))

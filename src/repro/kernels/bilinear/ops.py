@@ -1,38 +1,28 @@
 """jit'd public wrapper for the bilinear Pallas kernel.
 
-Handles padding to TPU-aligned shapes (rows to block_m, feature dim to a
-multiple of 128 lanes) and falls back to the jnp oracle on hosts where
-Mosaic is unavailable (CPU tests run the kernel with interpret=True via
-the ``force_interpret`` flag / REPRO_PALLAS_INTERPRET=1).
+``bilinear`` pads to TPU-aligned shapes (rows to block_m, feature dim to
+a multiple of 128 lanes); ``bilinear_batched`` needs no padding (its
+blocks span whole trailing dims).  Where each op runs is ``kernels.backend``'s
+rule: the kernel on TPU, the jnp oracle elsewhere, the interpreter under
+``force_interpret`` / ``REPRO_PALLAS_INTERPRET=1``.
 """
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..backend import interpret_requested, on_tpu
 from .bilinear import bilinear_batched_pallas, bilinear_pallas
 from .ref import bilinear_batched_ref, bilinear_ref
-
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1"
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover - no backend initialized
-        return False
 
 
 def bilinear(
     Z: jax.Array, W: jax.Array, *, block_m: int = 512, force_interpret: bool = False
 ) -> jax.Array:
     """p_i = z_i^T W z_i for all rows of Z, fused single-pass over Z."""
-    interpret = force_interpret or _INTERPRET
-    if not (_on_tpu() or interpret):
+    interpret = interpret_requested(force_interpret)
+    if not (on_tpu() or interpret):
         return bilinear_ref(Z, W)
     m, r = Z.shape
     r_pad = (-r) % 128
@@ -62,8 +52,8 @@ def bilinear_sharded(
         return bilinear(zl, w, block_m=block_m,
                         force_interpret=force_interpret)
 
-    f = shard_map(inner, mesh=mesh, in_specs=(P("model", None), P(None)),
-                  out_specs=P("model"), check_rep=False)
+    f = jax.shard_map(inner, mesh=mesh, in_specs=(P("model", None), P(None)),
+                      out_specs=P("model"), check_vma=False)
     return f(Z, W)
 
 
@@ -71,14 +61,9 @@ def bilinear_batched(
     Z: jax.Array, W: jax.Array, *, force_interpret: bool = False
 ) -> jax.Array:
     """p_{n,b} = z_{n,b}^T W_n z_{n,b}: one (B, R) row block and one (R, R)
-    inner matrix per batch element, fused in a single kernel over the batch."""
-    interpret = force_interpret or _INTERPRET
-    if not (_on_tpu() or interpret):
+    inner matrix per batch element, fused in a single kernel over the batch.
+    No padding: each block spans its array's last two dims whole."""
+    interpret = interpret_requested(force_interpret)
+    if not (on_tpu() or interpret):
         return bilinear_batched_ref(Z, W)
-    n, b, r = Z.shape
-    r_pad = (-r) % 128
-    b_pad = (-b) % 8
-    zp = jnp.pad(Z, ((0, 0), (0, b_pad), (0, r_pad)))
-    wp = jnp.pad(W, ((0, 0), (0, r_pad), (0, r_pad)))
-    out = bilinear_batched_pallas(zp, wp, interpret=interpret)
-    return out[:, :b]
+    return bilinear_batched_pallas(Z, W, interpret=interpret)

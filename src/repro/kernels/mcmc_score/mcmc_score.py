@@ -25,26 +25,30 @@ def _score_all_kernel(z_ref, a_ref, out_ref):
     z = z_ref[...]            # (BLK_M, R) VMEM
     a = a_ref[0]              # (R, R)     VMEM, resident per chain
     za = jnp.dot(z, a, preferred_element_type=jnp.float32)  # MXU
-    out_ref[0] = jnp.sum(za * z.astype(jnp.float32), axis=1)
+    out_ref[0] = jnp.sum(za * z.astype(jnp.float32), axis=1)[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
 def score_all_pallas(
     Z: jax.Array, A: jax.Array, *, block_m: int = 512, interpret: bool = False
 ) -> jax.Array:
-    """Z: (M, R), A: (C, R, R) -> (C, M) float32.  M % block_m == 0 and
-    R % 128 == 0 (ops.py pads)."""
+    """Z: (M, R), A: (C, R, R) -> (C, M) float32.  M % block_m == 0,
+    block_m % 128 == 0 or block_m == M, and R % 128 == 0 (ops.py pads).
+    Each program writes a (1, 1, block_m) block of a (C, 1, M) output, so
+    the block's last two dims satisfy the TPU tiling rule for any C."""
     m, r = Z.shape
     c = A.shape[0]
     assert m % block_m == 0, (m, block_m)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _score_all_kernel,
         grid=(c, m // block_m),
         in_specs=[
             pl.BlockSpec((block_m, r), lambda ci, mi: (mi, 0)),
             pl.BlockSpec((1, r, r), lambda ci, mi: (ci, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_m), lambda ci, mi: (ci, mi)),
-        out_shape=jax.ShapeDtypeStruct((c, m), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, block_m), lambda ci, mi: (ci, 0, mi)),
+        out_shape=jax.ShapeDtypeStruct((c, 1, m), jnp.float32),
         interpret=interpret,
+        name="ndpp_score_all",
     )(Z, A)
+    return out.reshape(c, m)

@@ -1,32 +1,21 @@
 """jit'd public wrapper for the MCMC all-candidate scorer.
 
 Pads to TPU-aligned shapes (rows to block_m, feature dim to a multiple of
-128 lanes) and falls back to the einsum oracle off-TPU
-(``REPRO_PALLAS_INTERPRET=1`` / ``force_interpret`` runs the kernel in
-interpreter mode instead).  Per-chain candidate *rows* (instead of the
-shared ground set) are the ``kernels.bilinear.ops.bilinear_batched``
-layout — use that op directly.
+128 lanes).  Where it runs is ``kernels.backend``'s rule: the kernel on
+TPU, the einsum oracle elsewhere, the interpreter under
+``force_interpret`` / ``REPRO_PALLAS_INTERPRET=1``.  Per-chain candidate
+*rows* (instead of the shared ground set) are the
+``kernels.bilinear.ops.bilinear_batched`` layout — use that op directly.
 """
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..backend import interpret_requested, on_tpu
 from .mcmc_score import score_all_pallas
 from .ref import score_all_ref
-
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1"
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover - no backend initialized
-        return False
 
 
 def score_all(
@@ -38,8 +27,8 @@ def score_all(
     Z: (M, R) ground-set features, A: (C, R, R) per-chain score matrices
     -> (C, M) float32 move scores (add ratios, or swap ratios when A is a
     swap score matrix)."""
-    interpret = force_interpret or _INTERPRET
-    if not (_on_tpu() or interpret):
+    interpret = interpret_requested(force_interpret)
+    if not (on_tpu() or interpret):
         return score_all_ref(Z, A)
     m, r = Z.shape
     r_pad = (-r) % 128
@@ -70,8 +59,8 @@ def score_all_sharded(
         return score_all(zl, a, block_m=block_m,
                          force_interpret=force_interpret)
 
-    f = shard_map(inner, mesh=mesh, in_specs=(P("model", None), P(None)),
-                  out_specs=P(None, "model"), check_rep=False)
+    f = jax.shard_map(inner, mesh=mesh, in_specs=(P("model", None), P(None)),
+                      out_specs=P(None, "model"), check_vma=False)
     return f(Z, A)
 
 
@@ -102,6 +91,6 @@ def score_argmax_sharded(
         c = jnp.arange(all_max.shape[1], dtype=jnp.int32)
         return all_max[win, c], all_arg[win, c]
 
-    f = shard_map(inner, mesh=mesh, in_specs=(P("model", None), P(None)),
-                  out_specs=(P(None), P(None)), check_rep=False)
+    f = jax.shard_map(inner, mesh=mesh, in_specs=(P("model", None), P(None)),
+                      out_specs=(P(None), P(None)), check_vma=False)
     return f(Z, A)

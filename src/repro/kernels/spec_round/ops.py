@@ -1,70 +1,70 @@
-"""jit'd public wrapper for the fused descent+score spec_round kernel.
+"""jit'd public wrapper for the tree-descent spec_round kernel.
 
-Dispatches the rejection hot path's per-round tree traversal + leaf
-scoring: the Pallas kernel on TPU (or under interpret), the pure-jnp
-oracle everywhere else.  The oracle *is* the committed CPU arithmetic —
-``core.tree.sample_elementary_batch`` routes through here, and the
-golden-file suite pins its draws bit-for-bit — so the ref path must not
-be "equivalent", it must be identical (see ref.py).
+Dispatches the rejection hot path's per-round tree traversal: the Pallas
+kernel on TPU (or under interpret), the pure-jnp oracle everywhere else.
+The oracle *is* the committed CPU arithmetic — ``core.tree`` routes its
+unsharded descent through here, and the golden-file suite pins its draws
+bit-for-bit — so the ref path must not be "equivalent", it must be
+identical (see ref.py).
+
+The kernel reads the levels in a flat (S, 128) node layout.
+``descent_operands`` builds it once per round, outside the per-item loop
+of ``core.tree.sample_elementary_batch``; ``descend`` then runs one
+descent per item step.
 """
 from __future__ import annotations
 
-import os
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from .ref import descend_ref, descend_score_ref, leaf_scores_ref  # noqa: F401
-from .spec_round import descend_score_pallas
+from ..backend import interpret_requested, on_tpu
+from .ref import descend_ref
+from .spec_round import descend_pallas
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1"
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover - no backend initialized
-        return False
+#: proposal lanes per grid step: their node DMAs are in flight together
+LANES = 16
 
 
-def descend_score(
-    levels, W: jax.Array, block: int, q: jax.Array, us: jax.Array, *,
-    force_interpret: bool = False,
-) -> Tuple[jax.Array, jax.Array]:
-    """Fused per-round descent + leaf scoring for N proposal lanes.
+def flat_nodes(x: jax.Array) -> jax.Array:
+    """(n, R, R) -> (n, S, 128) float32: each node flattened and
+    zero-padded to S = 8 * ceil(R^2 / 1024) rows of 128 lanes."""
+    n = x.shape[0]
+    f = x.reshape(n, -1).astype(jnp.float32)
+    size = -(-f.shape[1] // 1024) * 1024
+    f = jnp.pad(f, ((0, 0), (0, size - f.shape[1])))
+    return f.reshape(n, size // 128, 128)
 
-    levels: tuple of (2^lvl, R, R) tree node arrays (root first); W:
-    (m_pad, R) leaf rows; q: (N, R, R) conditioning projectors; us:
-    (N, depth) descent uniforms.  Returns (block ids (N,) int32, raw
-    unclamped scores (N, block) float32) — the caller owns the
-    ``maximum(., 0)`` clamp and the categorical draw, whose PRNG stream
-    must stay outside the kernel for bit-stable draws.
+
+def descent_operands(levels, *, force_interpret: bool = False
+                     ) -> Optional[Tuple[jax.Array, ...]]:
+    """The levels in the kernel's flat node layout, or None where the
+    jnp oracle runs (off TPU, or a one-level tree with nothing to
+    descend)."""
+    if len(levels) == 1 or not (on_tpu()
+                                or interpret_requested(force_interpret)):
+        return None
+    return tuple(flat_nodes(lvl) for lvl in levels)
+
+
+def descend(levels, flat_levels: Optional[Tuple[jax.Array, ...]],
+            q: jax.Array, us: jax.Array, *,
+            force_interpret: bool = False) -> jax.Array:
+    """Root-to-block traversal for N proposal lanes.
+
+    levels: tuple of (2^lvl, R, R) tree node arrays (root first);
+    flat_levels: ``descent_operands(levels)``; q: (N, R, R) conditioning
+    projectors; us: (N, >= depth) descent uniforms.  Returns the chosen
+    block ids (N,) int32 — identical between the kernel and the oracle.
     """
-    interpret = force_interpret or _INTERPRET
+    if flat_levels is None:
+        return descend_ref(levels, q, us)
+    n = q.shape[0]
     depth = len(levels) - 1
-    if depth == 0 or not (_on_tpu() or interpret):
-        with jax.named_scope("ndpp.tree_descent"):
-            blk = descend_ref(levels, q, us)
-        with jax.named_scope("ndpp.leaf_scoring"):
-            scores = leaf_scores_ref(W, block, blk, q)
-        return blk, scores
-    m, r = W.shape
-    assert m % block == 0, (m, block)
-    r_pad = (-r) % 128
-    b_pad = (-block) % 8
-    lv = jnp.concatenate([lvl.reshape(-1, r, r) for lvl in levels])
-    lvp = jnp.pad(lv.astype(jnp.float32),
-                  ((0, 0), (0, r_pad), (0, r_pad)))
-    wb = W.reshape(m // block, block, r)
-    wbp = jnp.pad(wb, ((0, 0), (0, b_pad), (0, r_pad)))
-    qp = jnp.pad(q, ((0, 0), (0, r_pad), (0, r_pad)))
-    offsets, off = [], 0
-    for lvl_arr in levels:
-        offsets.append(off)
-        off += lvl_arr.shape[0]
-    with jax.named_scope("ndpp.tree_descent"):
-        blk, sc = descend_score_pallas(
-            lvp, wbp, qp, us[:, :depth], offsets=tuple(offsets),
-            interpret=interpret)
-    return blk[:, 0], sc[:, :block]
+    n_pad = (-n) % LANES
+    qf = jnp.pad(flat_nodes(q), ((0, n_pad), (0, 0), (0, 0)))
+    usp = jnp.pad(us[:, :depth].astype(jnp.float32), ((0, n_pad), (0, 0)))
+    blk = descend_pallas(flat_levels, qf, usp, lanes=LANES,
+                         interpret=interpret_requested(force_interpret))
+    return blk[:n]

@@ -1,17 +1,13 @@
-"""Pure-jnp oracle for the fused speculative-round descent+score kernel.
+"""Pure-jnp oracle for the speculative-round tree-descent kernel.
 
-``descend_score_ref`` is the arithmetic the CPU CI actually executes for
-the rejection hot path: it must stay expression-for-expression identical
-to the inline stages it fused (``core.tree._descend_batch``'s unsharded
-branch and the einsum of ``kernels.bilinear.ref.bilinear_batched_ref``),
-because the golden-file suite pins the sampler's draws bit-for-bit.
-Changing an op order here is a distribution change and must go through
-``--regen-golden`` review.
+``descend_ref`` is the arithmetic the CPU CI actually executes for the
+rejection hot path: it must stay expression-for-expression identical to
+``core.tree._descend_batch``'s unsharded branch, because the golden-file
+suite pins the sampler's draws bit-for-bit.  Changing an op order here is
+a distribution change and must go through ``--regen-golden`` review.
 """
 import jax
 import jax.numpy as jnp
-
-from ..bilinear.ref import bilinear_batched_ref
 
 #: levels whose whole node set is scored with one stacked matmul instead
 #: of per-lane gathers — must match ``core.tree._SHALLOW_MAX`` (the plain
@@ -62,20 +58,3 @@ def descend_ref(levels, q: jax.Array, us: jax.Array) -> jax.Array:
         p_all = jnp.maximum(jnp.where(go_left, p_left, p_all - p_left), 0.0)
     return idx
 
-
-def leaf_scores_ref(W: jax.Array, block: int, blk: jax.Array,
-                    q: jax.Array) -> jax.Array:
-    """Raw (unclamped) leaf-block scores: gather each lane's (block, R)
-    leaf rows of W and bilinear-score them against the lane's projector —
-    the einsum of ``bilinear_batched_ref``, byte for byte."""
-    blk_ar = jnp.arange(block, dtype=jnp.int32)
-    rows = blk[:, None] * block + blk_ar[None, :]   # (N, block)
-    w_blk = W[rows]                                  # (N, block, R)
-    return bilinear_batched_ref(w_blk, q)
-
-
-def descend_score_ref(levels, W: jax.Array, block: int, q: jax.Array,
-                      us: jax.Array):
-    """Fused oracle: (chosen block indices (N,), raw scores (N, block))."""
-    blk = descend_ref(levels, q, us)
-    return blk, leaf_scores_ref(W, block, blk, q)

@@ -7,23 +7,14 @@ mamba2/jamba train steps compile to dense compute).
 """
 from __future__ import annotations
 
-import os
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..backend import interpret_requested, on_tpu
 from .ref import ssd_ref
 from .ssd import ssd_pallas
-
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1"
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover - no backend initialized
-        return False
 
 
 def ssd_chunked_ref(
@@ -93,10 +84,10 @@ def ssd(
     force_interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Mamba2 SSD scan.  x: (B,S,H,P), a: (B,S,H), b/c: (B,S,H,N)."""
-    interpret = force_interpret or _INTERPRET
+    interpret = interpret_requested(force_interpret)
     bsz, s, h, p = x.shape
     usable = (
-        (_on_tpu() or interpret)
+        (on_tpu() or interpret)
         and h0 is None
         and s % chunk == 0
         and p % 8 == 0

@@ -1,31 +1,22 @@
 """jit'd public wrappers for the tree_sum Pallas kernels."""
 from __future__ import annotations
 
-import os
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..backend import interpret_requested, on_tpu
 from .ref import block_outer_sums_ref, gathered_block_grams_ref
 from .tree_sum import block_outer_sums_pallas, gathered_block_grams_pallas
-
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1"
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover - no backend initialized
-        return False
 
 
 def block_outer_sums(
     W: jax.Array, block: int, *, force_interpret: bool = False
 ) -> jax.Array:
     """W: (n*block, R) -> (n, R, R) per-block Gram matrices."""
-    interpret = force_interpret or _INTERPRET
-    if not (_on_tpu() or interpret):
+    interpret = interpret_requested(force_interpret)
+    if not (on_tpu() or interpret):
         return block_outer_sums_ref(W, block)
     m, r = W.shape
     r_pad = (-r) % 128
@@ -38,8 +29,8 @@ def gathered_block_grams(
     W: jax.Array, blks: jax.Array, block: int, *, force_interpret: bool = False
 ) -> jax.Array:
     """Grams of the leaf blocks named by ``blks`` only: (nb,) -> (nb, R, R)."""
-    interpret = force_interpret or _INTERPRET
-    if not (_on_tpu() or interpret):
+    interpret = interpret_requested(force_interpret)
+    if not (on_tpu() or interpret):
         return gathered_block_grams_ref(W, blks, block)
     m, r = W.shape
     r_pad = (-r) % 128

@@ -6,7 +6,10 @@ consecutive items, the matrix  Σ_n = Z_n^T Z_n  (R x R).  On TPU this is one
 HBM exactly once.  Upper tree levels are pairwise sums of these outputs
 (done by the caller; they touch (M/block) * R^2 bytes, negligible).
 
-Grid: (n_blocks,).  block and R are MXU-aligned by the ops.py wrapper.
+Grid: (n_blocks,).  W is viewed as (n_blocks, block, R) so each step's
+(block, R) tile spans the array's last two dims whole — legal for the TPU
+tiling rule at any ``block`` (the leaf block of a tiny tree may be 2).
+R is lane-padded to 128 by the ops.py wrapper.
 """
 from __future__ import annotations
 
@@ -18,7 +21,7 @@ from jax.experimental import pallas as pl
 
 
 def _tree_sum_kernel(z_ref, out_ref):
-    z = z_ref[...]  # (block, R) VMEM
+    z = z_ref[0]  # (block, R) VMEM
     zf = z.astype(jnp.float32)
     out_ref[...] = jnp.dot(zf.T, zf, preferred_element_type=jnp.float32)[None]
 
@@ -33,11 +36,12 @@ def block_outer_sums_pallas(
     return pl.pallas_call(
         _tree_sum_kernel,
         grid=(n,),
-        in_specs=[pl.BlockSpec((block, r), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((1, block, r), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((1, r, r), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, r, r), jnp.float32),
         interpret=interpret,
-    )(W)
+        name="ndpp_block_outer_sums",
+    )(W.reshape(n, block, r))
 
 
 def _gathered_gram_kernel(blk_ref, w_ref, out_ref):
@@ -45,7 +49,7 @@ def _gathered_gram_kernel(blk_ref, w_ref, out_ref):
     # used it to DMA exactly the touched (block, R) tile of W into VMEM, so
     # the body is the same single MXU Gram as the full construction kernel —
     # recomputed blocks are bit-equal to a from-scratch build.
-    z = w_ref[...]
+    z = w_ref[0]
     zf = z.astype(jnp.float32)
     out_ref[...] = jnp.dot(zf.T, zf, preferred_element_type=jnp.float32)[None]
 
@@ -66,7 +70,8 @@ def gathered_block_grams_pallas(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nb,),
-        in_specs=[pl.BlockSpec((block, r), lambda i, blk_ref: (blk_ref[i], 0))],
+        in_specs=[pl.BlockSpec((1, block, r),
+                               lambda i, blk_ref: (blk_ref[i], 0, 0))],
         out_specs=pl.BlockSpec((1, r, r), lambda i, blk_ref: (i, 0, 0)),
     )
     return pl.pallas_call(
@@ -74,4 +79,5 @@ def gathered_block_grams_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb, r, r), jnp.float32),
         interpret=interpret,
-    )(blks.astype(jnp.int32), W)
+        name="ndpp_gathered_block_grams",
+    )(blks.astype(jnp.int32), W.reshape(m // block, block, r))

@@ -170,8 +170,6 @@ def scatter_rows_sharded(
     """``scatter_rows`` over a mesh: keeps the (M, R) rows device-local
     while every shard applies only the updates it owns.  Falls back to a
     plain functional scatter when Z does not divide the mesh."""
-    from jax.experimental.shard_map import shard_map
-
     spec = logical_to_spec(mesh, ("items", None), Z.shape)
     if model_extent(mesh) == 1 or spec == P(None, None) or spec[0] is None:
         return Z.at[idx].set(rows)
@@ -179,8 +177,9 @@ def scatter_rows_sharded(
     def inner(z_loc, idx, rows):
         return scatter_rows(z_loc, idx, rows, axis_name="model")
 
-    f = shard_map(inner, mesh=mesh, in_specs=(spec, P(None), P(None, None)),
-                  out_specs=spec, check_rep=False)
+    f = jax.shard_map(inner, mesh=mesh,
+                      in_specs=(spec, P(None), P(None, None)),
+                      out_specs=spec, check_vma=False)
     return f(Z, idx, rows)
 
 

@@ -453,7 +453,8 @@ class SamplerEngine:
         if self.mcmc_k is None:
             return mcmc_core.init_empty(self.sp)
         greedy_key = jax.random.fold_in(jax.random.PRNGKey(seed), 0x67726479)
-        st = mcmc_core.init_greedy(self.sp, greedy_key, 1, self.mcmc_k)
+        st = mcmc_core.init_greedy(self.sp, greedy_key, 1, self.mcmc_k,
+                                   mesh=self.mesh)
         return jax.tree_util.tree_map(lambda a: a[0], st)
 
     def _admit(self):

@@ -41,7 +41,11 @@ N_SAMPLES = 8000
 
 
 @pytest.fixture(scope="module")
-def params(rng):
+def params():
+    # module-local RNG: the kernel must not depend on which test files ran
+    # earlier in the same worker (the truncation test needs a request that
+    # outlives its first round)
+    rng = np.random.default_rng(0)
     v = jnp.asarray(rng.normal(size=(M, K)) * 0.6, jnp.float32)
     b = jnp.asarray(rng.normal(size=(M, K)) * 0.6, jnp.float32)
     d = jnp.asarray(rng.normal(size=(K, K)), jnp.float32)

@@ -11,7 +11,7 @@ from repro.kernels.bilinear.ref import bilinear_batched_ref, bilinear_ref
 from repro.kernels.mcmc_score import ops as mops
 from repro.kernels.mcmc_score.ref import score_all_ref
 from repro.kernels.spec_round import ops as spops
-from repro.kernels.spec_round.ref import descend_score_ref
+from repro.kernels.spec_round.ref import descend_ref
 from repro.kernels.ssd import ops as sops
 from repro.kernels.ssd.ref import ssd_ref
 from repro.kernels.tree_sum import ops as tops
@@ -102,9 +102,11 @@ def _random_tree_levels(rng, depth, r):
 @pytest.mark.parametrize("depth,block,r,n", [(3, 4, 8, 5), (5, 8, 16, 12),
                                              (6, 2, 40, 3), (2, 8, 130, 4)])
 def test_spec_round_descend_score(depth, block, r, n):
-    """Fused descent+score megakernel (interpret mode) vs the jnp oracle:
-    identical block choices, matching raw leaf scores.  Spans shallow-only
-    trees (depth <= 5 under _SHALLOW_MAX=32) and deep per-lane gathers."""
+    """HBM-resident descent kernel (interpret mode) vs the jnp oracle:
+    identical block choices, then matching raw leaf scores of the chosen
+    blocks.  Spans shallow-only trees (depth <= 5 under _SHALLOW_MAX=32),
+    deep per-lane gathers, lane counts off the kernel's lane multiple, and
+    R past one 1024-float node slab."""
     rng = np.random.default_rng(depth * 1000 + block * 100 + r)
     levels = _random_tree_levels(rng, depth, r)
     m = (1 << depth) * block
@@ -112,11 +114,16 @@ def test_spec_round_descend_score(depth, block, r, n):
     qh = rng.normal(size=(n, r, r)).astype(np.float32)
     q = jnp.asarray(np.einsum("nik,njk->nij", qh, qh) / r)
     us = jnp.asarray(rng.uniform(size=(n, depth)), jnp.float32)
-    blk, sc = spops.descend_score(levels, w, block, q, us,
-                                  force_interpret=True)
-    blk_ref, sc_ref = descend_score_ref(levels, w, block, q, us)
+    flat = spops.descent_operands(levels, force_interpret=True)
+    assert flat is not None and flat[-1].shape[1:] == (
+        -(-r * r // 1024) * 8, 128)
+    blk = spops.descend(levels, flat, q, us, force_interpret=True)
+    blk_ref = descend_ref(levels, q, us)
     np.testing.assert_array_equal(np.asarray(blk), np.asarray(blk_ref))
-    np.testing.assert_allclose(np.asarray(sc), np.asarray(sc_ref),
+    rows = blk_ref[:, None] * block + jnp.arange(block)[None, :]
+    sc = bops.bilinear_batched(w[rows], q, force_interpret=True)
+    np.testing.assert_allclose(np.asarray(sc),
+                               np.asarray(bilinear_batched_ref(w[rows], q)),
                                rtol=1e-4, atol=1e-4 * max(1, r))
 
 

@@ -30,7 +30,11 @@ from repro.core import (
     project_constraints,
     symmetric_dpp_loss,
 )
-from repro.core.learning import _DET_EPS, _basket_logdets
+from repro.core.learning import (
+    _DET_EPS,
+    _basket_logdets,
+    _slogdet_width_invariant,
+)
 from repro.core.types import NDPPParams, ONDPPParams, dense_l
 
 SETTINGS = dict(max_examples=15, deadline=None)
@@ -139,6 +143,26 @@ def test_basket_logdets_padding_invariant(seed, k_max_a):
     a = np.asarray(_basket_logdets(V, B, D, b1))
     b = np.asarray(_basket_logdets(V, B, D, b2))
     np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+
+@settings(**SETTINGS)
+@given(seed=st.integers(0, 10_000), k=st.integers(1, 9))
+def test_slogdet_width_invariant_matches_lapack(seed, k):
+    """The elimination behind _basket_logdets gives the f64 sign and log
+    |det| of dense square matrices, negative determinants included
+    (Q1 diag(s) Q2 with |s| in [0.5, 2] and random signs: condition
+    number at most 4, so float32 cannot flip a sign)."""
+    rng = np.random.default_rng(seed)
+    q1 = np.linalg.qr(rng.normal(size=(6, k, k)))[0]
+    q2 = np.linalg.qr(rng.normal(size=(6, k, k)))[0]
+    s = (rng.uniform(0.5, 2.0, size=(6, 1, k))
+         * rng.choice([-1.0, 1.0], size=(6, 1, k)))
+    a = (q1 * s) @ q2
+    sign, logdet = _slogdet_width_invariant(jnp.asarray(a, jnp.float32))
+    ref_sign, ref_logdet = np.linalg.slogdet(a)
+    np.testing.assert_array_equal(np.asarray(sign), ref_sign)
+    np.testing.assert_allclose(np.asarray(logdet), ref_logdet,
+                               rtol=1e-4, atol=1e-4)
 
 
 @settings(**SETTINGS)

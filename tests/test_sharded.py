@@ -11,7 +11,8 @@ In-process tests run on a 1-device ("model",) mesh (the full shard_map
 machinery — specs, masking, psums — with S = 1).  The 2-simulated-device
 cases need ``XLA_FLAGS=--xla_force_host_platform_device_count=2`` set
 before jax initializes, so they run in a subprocess: bit-equality for
-the sharded tree descent / rejection round / MCMC chains, plus
+the sharded tree descent / rejection round / MCMC chains and greedy
+chain starts, plus
 distribution-equality of the sharded rejection sampler against the
 enumerated target (the ``tests/_exactness.py`` chi-square bar).
 """
@@ -28,6 +29,7 @@ from jax.sharding import Mesh
 
 from repro.core import (
     init_empty,
+    init_greedy,
     preprocess,
     run_chains,
     run_chains_sharded,
@@ -128,6 +130,18 @@ def test_mcmc_sharded_bit_equal(sampler, mesh1):
     assert np.array_equal(np.asarray(ac0), np.asarray(ac1))
 
 
+def test_init_greedy_sharded_bit_equal(sampler, mesh1):
+    """Greedy size-k starts with the rows sharded (each shard scores its
+    own rows) == the unsharded starts."""
+    key = jax.random.PRNGKey(9)
+    st0 = init_greedy(sampler.sp, key, 4, 3)
+    sh = shard_sampler(sampler, mesh1)
+    st1 = init_greedy(sh.sp, key, 4, 3, mesh=mesh1)
+    assert np.array_equal(np.asarray(st0.items), np.asarray(st1.items))
+    assert np.array_equal(np.asarray(st0.mask), np.asarray(st1.mask))
+    assert np.array_equal(np.asarray(st0.minv), np.asarray(st1.minv))
+
+
 def test_engine_mesh_parity(sampler, mesh1):
     """SamplerEngine(mesh=) retires every request with the exact result
     the meshless engine produces, for both backends."""
@@ -139,7 +153,9 @@ def test_engine_mesh_parity(sampler, mesh1):
         return eng.run()
 
     for backend, kw in (("rejection", dict(n_spec=4)),
-                        ("mcmc", dict(mcmc_burn_in=32, mcmc_thin=8))):
+                        ("mcmc", dict(mcmc_burn_in=32, mcmc_thin=8)),
+                        ("mcmc", dict(mcmc_k=3, mcmc_burn_in=32,
+                                      mcmc_thin=8))):
         o0 = drain(None, backend, **kw)
         o1 = drain(mesh1, backend, **kw)
         assert sorted(o0) == sorted(o1) == list(range(7))
@@ -157,9 +173,9 @@ _TWO_DEV_SCRIPT = textwrap.dedent("""
     assert len(jax.devices()) == 2, jax.devices()
     mesh = Mesh(np.asarray(jax.devices()), ("model",))
 
-    from repro.core import (init_empty, preprocess, run_chains,
-                            run_chains_sharded, sample_batched_many,
-                            shard_sampler)
+    from repro.core import (init_empty, init_greedy, preprocess,
+                            run_chains, run_chains_sharded,
+                            sample_batched_many, shard_sampler)
     from repro.core.types import NDPPParams, dense_l
     from _exactness import (assert_chi_square_close, enumerate_subset_probs,
                             histogram)
@@ -192,6 +208,12 @@ _TWO_DEV_SCRIPT = textwrap.dedent("""
     assert np.array_equal(np.asarray(mk0), np.asarray(mk1))
     assert np.array_equal(np.asarray(ac0), np.asarray(ac1))
     print("mcmc 2-dev bit-equality ok")
+
+    g0 = init_greedy(sampler.sp, jax.random.PRNGKey(2), 4, 3)
+    g1 = init_greedy(sh.sp, jax.random.PRNGKey(2), 4, 3, mesh=mesh)
+    assert np.array_equal(np.asarray(g0.items), np.asarray(g1.items))
+    assert np.array_equal(np.asarray(g0.minv), np.asarray(g1.minv))
+    print("greedy init 2-dev bit-equality ok")
 
     # --- distribution equality of the sharded rejection sampler ----------
     # tiny ground set -> exact target by enumeration, chi-square bar

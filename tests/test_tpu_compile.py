@@ -1,0 +1,190 @@
+"""Ahead-of-time compiles of the sampling path's Pallas kernels for a
+described TPU v5e, at the widths ``chip_smoke.py`` runs.
+
+Nothing runs: the TPU compiler (installed with JAX) compiles each kernel
+for a chip that is described, not attached, and raises what the chip's
+compiler would raise — a block that breaks the (8, 128) tiling rule, or
+more VMEM than a kernel may use.  Interpret mode shows neither.
+
+Two widths, each laid out as the ops wrappers pass it:
+
+* catalog — M = 2^20 items at rank R = 100, leaf block 64 (tree depth
+  14), 8 slots x 64 speculative proposals = 512 lanes, 16 MCMC chains;
+* exactness — M = 8, R = 4, leaf block 2 (depth 2), 500 requests x 4
+  proposals = 2000 lanes: the smoke's on-chip distribution check.
+
+The ops wrappers take their CPU branch here, so the kernel tests call
+the ``*_pallas`` functions directly; the sharded-path test tells the ops
+wrappers they are on a TPU instead, and compiles whole jitted programs
+over all four chips of the described host.  The topology is described
+inside a fixture only: the TPU library may be loaded by one process at a
+time, and every test worker imports this file.
+"""
+import os
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import mcmc as mcmc_core
+from repro.core.rejection import NDPPSampler, _spec_round_fused_sharded
+from repro.core.tree import construct_tree, proposal_eigens, tree_shard_specs
+from repro.core.types import SpectralNDPP
+from repro.kernels.bilinear import ops as bilinear_ops
+from repro.kernels.bilinear.bilinear import bilinear_batched_pallas
+from repro.kernels.mcmc_score import ops as mcmc_score_ops
+from repro.kernels.mcmc_score.mcmc_score import score_all_pallas
+from repro.kernels.spec_round import ops as spec_round_ops
+from repro.kernels.spec_round.spec_round import descend_pallas
+from repro.kernels.tree_sum.tree_sum import (
+    block_outer_sums_pallas,
+    gathered_block_grams_pallas,
+)
+
+
+class Width(NamedTuple):
+    m: int
+    r: int
+    block: int
+    lanes: int
+    chains: int
+
+    @property
+    def depth(self) -> int:
+        return (self.m // self.block).bit_length() - 1
+
+    @property
+    def r_pad(self) -> int:          # tree_sum / score_all lane padding
+        return -(-self.r // 128) * 128
+
+
+WIDTHS = {
+    "catalog": Width(m=1 << 20, r=100, block=64, lanes=8 * 64, chains=16),
+    "exactness": Width(m=8, r=4, block=2, lanes=500 * 4, chains=4),
+}
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_devices):
+    return SingleDeviceSharding(v5e_devices[0])
+
+
+def _shape(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernels(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_descend_compiles(one_chip, width):
+    """The HBM-resident tree descent: levels stay in HBM, one node per
+    lane per level is DMA'd, so a 2^14-block tree compiles."""
+    w = WIDTHS[width]
+    s = spec_round_ops.flat_nodes(jnp.zeros((1, w.r, w.r))).shape[1]
+    levels = tuple(_shape(one_chip, (1 << lvl, s, 128))
+                   for lvl in range(w.depth + 1))
+    c = jax.jit(descend_pallas, static_argnames=("lanes",)).lower(
+        levels, _shape(one_chip, (w.lanes, s, 128)),
+        _shape(one_chip, (w.lanes, w.depth)), lanes=spec_round_ops.LANES,
+    ).compile()
+    assert _kernels(c) == 1
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_bilinear_batched_compiles(one_chip, width):
+    """Leaf-block scoring: one (block, R) x (R, R) form per lane."""
+    w = WIDTHS[width]
+    c = bilinear_batched_pallas.lower(
+        _shape(one_chip, (w.lanes, w.block, w.r)),
+        _shape(one_chip, (w.lanes, w.r, w.r)),
+    ).compile()
+    assert _kernels(c) == 1
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_score_all_compiles(one_chip, width):
+    """MCMC all-candidate scores: every item against every chain."""
+    w = WIDTHS[width]
+    c = score_all_pallas.lower(
+        _shape(one_chip, (max(w.m, 8), w.r_pad)),
+        _shape(one_chip, (w.chains, w.r_pad, w.r_pad)),
+        block_m=min(512, max(w.m, 8)),
+    ).compile()
+    assert _kernels(c) == 1
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_block_outer_sums_compiles(one_chip, width):
+    """Leaf-level Grams of tree construction, any leaf block size."""
+    w = WIDTHS[width]
+    c = block_outer_sums_pallas.lower(
+        _shape(one_chip, (w.m, w.r_pad)), block=w.block).compile()
+    assert _kernels(c) == 1
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_gathered_block_grams_compiles(one_chip, width):
+    """Leaf Grams recomputed for 16 updated blocks (catalog updates)."""
+    w = WIDTHS[width]
+    c = gathered_block_grams_pallas.lower(
+        _shape(one_chip, (w.m, w.r_pad)), _shape(one_chip, (16,), jnp.int32),
+        block=w.block).compile()
+    assert _kernels(c) == 1
+
+
+def test_sharded_paths_compile(v5e_devices, monkeypatch):
+    """The item-sharded engines' programs over a 4-chip mesh at the catalog
+    width: the rejection tick and the MCMC greedy init.  XLA cannot
+    partition a Pallas kernel, so each one must sit inside a shard_map."""
+    for ops in (bilinear_ops, mcmc_score_ops):
+        monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    w = WIDTHS["catalog"]
+    mesh = Mesh(np.asarray(v5e_devices[:4]), ("model",))
+
+    def build(z, sigma):
+        sp = SpectralNDPP(Z=z, sigma=sigma)
+        return NDPPSampler(sp=sp, tree=construct_tree(*proposal_eigens(sp),
+                                                      block=w.block))
+
+    shapes = jax.eval_shape(build, jax.ShapeDtypeStruct((w.m, w.r), jnp.float32),
+                            jax.ShapeDtypeStruct((w.r // 4,), jnp.float32))
+    specs = NDPPSampler(sp=SpectralNDPP(Z=P("model", None), sigma=P(None)),
+                        tree=tree_shard_specs(shapes.tree, mesh))
+    sampler = jax.tree.map(
+        lambda a, spec: _shape(NamedSharding(mesh, spec), a.shape, a.dtype),
+        shapes, specs, is_leaf=lambda x: isinstance(x, P))
+    rep = NamedSharding(mesh, P())
+    slots = 8
+    tick = _spec_round_fused_sharded.lower(
+        sampler, _shape(rep, (slots, 2), jnp.uint32),
+        _shape(rep, (slots,), jnp.uint32), mesh, n_spec=w.lanes // slots,
+    ).compile()
+    assert _kernels(tick) >= 1
+    states = jax.tree.map(
+        lambda a: _shape(rep, a.shape, a.dtype),
+        jax.eval_shape(lambda sp: jax.vmap(
+            lambda _: mcmc_core.init_empty(sp))(jnp.arange(1)), sampler.sp))
+    init = mcmc_core._greedy_round.lower(
+        sampler.sp, states, _shape(rep, (1, 2), jnp.uint32),
+        _shape(rep, (), jnp.int32), mesh=mesh,
+    ).compile()
+    assert _kernels(init) >= 1
