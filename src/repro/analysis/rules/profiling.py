@@ -16,8 +16,8 @@ only as honest as the instrumentation discipline:
            ``repro.obs.trace`` gate.  Direct construction bypasses the
            ``NDPP_PROFILE`` env gate (annotations leak into production
            runs) and the ``ndpp_phase/`` naming convention the trace
-           parser keys on — route through ``repro.obs.trace.annotation``
-           / ``phase_annotation`` instead.
+           parser keys on — route through ``repro.obs.trace.span`` (or
+           ``annotation``, the gated constructor it uses) instead.
 
 NDPP701 matches both spellings of the sanctioned phase: the string
 literal ``phase("harvest")`` (as in ``drive_rounds``) and the catalog
@@ -149,5 +149,5 @@ def raw_trace_annotation(mod: Module) -> Iterator[Finding]:
             "TraceAnnotation constructed directly — production runs "
             "would pay annotation overhead with NDPP_PROFILE unset, and "
             "ad-hoc names are invisible to the attribution parser; use "
-            "repro.obs.trace.annotation / phase_annotation (the gated "
-            "constructors)")
+            "repro.obs.trace.span (the gated span primitive) or "
+            "repro.obs.trace.annotation")

@@ -41,13 +41,17 @@ class CompileCounter:
     Any compile inside the region counts — including compiles of helper
     computations like array constructors — which is exactly the property
     a steady-state tick loop must preserve: after warmup, *nothing*
-    compiles.
+    compiles.  ``seconds`` sums the events' durations the same way
+    (``repro.obs.setup_stage`` reads its deltas); with the persistent
+    compilation cache on, the event also fires on a cache load and times
+    it.
     """
 
     _instance: Optional["CompileCounter"] = None
 
     def __init__(self) -> None:
         self.count = 0
+        self.seconds = 0.0   # summed backend-compile durations
 
     @classmethod
     def install(cls) -> "CompileCounter":
@@ -59,6 +63,7 @@ class CompileCounter:
             def _listener(name: str, secs: float, **kw) -> None:
                 if name == _COMPILE_EVENT:
                     inst.count += 1
+                    inst.seconds += secs
 
             monitoring.register_event_duration_secs_listener(_listener)
             cls._instance = inst

@@ -162,7 +162,7 @@ def _spec_round_dual_impl(prop: DualProposal, live_sp: SpectralNDPP,
     """Traced body of one dual-proposal round (shared by the standalone
     dispatch and the fused variant that folds the key fan-out in)."""
     # scope names from the repro.obs.prof.phases catalog (free HLO
-    # metadata; core stays import-free of repro.obs)
+    # metadata; traced bodies never touch repro.obs)
     ks = jax.vmap(jax.random.split)(keys)
     with jax.named_scope("ndpp.proposal"):
         items, mask = sample_proposal_dpp_batch(prop.tree, ks[:, 0],

@@ -378,7 +378,7 @@ def run_chains(sp: SpectralNDPP, chain_keys: jax.Array, states: MCMCState,
     across.
     """
     # scope name from the repro.obs.prof.phases catalog (free HLO
-    # metadata; core stays import-free of repro.obs)
+    # metadata; traced bodies never touch repro.obs)
     with jax.named_scope("ndpp.mcmc_step"):
         x = sp.x_matrix()
         return jax.vmap(

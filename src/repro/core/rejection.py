@@ -73,12 +73,23 @@ jax.tree_util.register_pytree_node(
 
 
 def preprocess(V: jax.Array, B: jax.Array, D: jax.Array, block: int = 64) -> NDPPSampler:
-    """PREPROCESS of Algorithm 2 (+ tree construction of Algorithm 3)."""
+    """PREPROCESS of Algorithm 2 (+ tree construction of Algorithm 3).
+
+    Eager host code, never traced: each step is a set-up stage
+    (``repro.obs.setup_stage``) that blocks on its outputs, so
+    ``ndpp_setup_seconds_total`` splits the set-up time between them.
+    """
+    from repro.obs import setup_stage
+    from repro.obs.prof import phases
+
     from .youla import spectral_from_params
 
-    sp = spectral_from_params(V, B, D)
-    lam, w = proposal_eigens(sp)
-    tree = construct_tree(lam, w, block=block)
+    with setup_stage(phases.YOULA):
+        sp = jax.block_until_ready(spectral_from_params(V, B, D))
+    with setup_stage(phases.PROPOSAL_EIGENS):
+        lam, w = jax.block_until_ready(proposal_eigens(sp))
+    with setup_stage(phases.TREE_BUILD):
+        tree = jax.block_until_ready(construct_tree(lam, w, block=block))
     return NDPPSampler(sp=sp, tree=tree)
 
 
@@ -226,7 +237,7 @@ def _spec_round_impl(sampler: NDPPSampler, keys: jax.Array):
     ``_spec_round_fused`` (fan-out folded into the same jit), and the
     device-resident round loop of ``_drive_rounds_fused``."""
     # scope names from the repro.obs.prof.phases catalog (free HLO
-    # metadata; core stays import-free of repro.obs)
+    # metadata; traced bodies never touch repro.obs)
     ks = jax.vmap(jax.random.split)(keys)
     with jax.named_scope("ndpp.proposal"):
         items, mask = sample_proposal_dpp_batch(sampler.tree, ks[:, 0])
@@ -527,8 +538,8 @@ def drive_rounds(
     no sync points and cannot perturb the draws).  An observer may also
     provide a ``phase(name)`` context-manager hook (profiler scopes: the
     round dispatch and the harvest sync get named ranges —
-    ``repro.obs.prof.phases``).  ``core`` stays free of telemetry
-    imports; pass e.g. ``repro.obs.RegistryObserver``.
+    ``repro.obs.prof.phases``).  The traced round bodies stay free of
+    telemetry; pass e.g. ``repro.obs.RegistryObserver``.
     """
     phase = getattr(observer, "phase", None) or (lambda name: _NO_PHASE)
     n = req_keys.shape[0]

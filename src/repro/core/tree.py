@@ -448,8 +448,10 @@ def sample_elementary_batch(
                  and w_rows != m_pad_global)
     shard = None if axis_name is None else jax.lax.axis_index(axis_name)
     # the kernel's node layout is built once here, not once per item step
-    flat_levels = (spec_round_ops.descent_operands(tree.levels)
-                   if axis_name is None else None)
+    # (scope name from the repro.obs.prof.phases catalog)
+    with jax.named_scope("ndpp.descent_operands"):
+        flat_levels = (spec_round_ops.descent_operands(tree.levels)
+                       if axis_name is None else None)
 
     def cond(state):
         t, _, _ = state
@@ -463,8 +465,8 @@ def sample_elementary_batch(
             lambda k: jax.random.uniform(k, (depth,), dtype=tree.W.dtype)
         )(kk[:, 0])
         # named scopes are compile-time HLO metadata (free at runtime);
-        # names come from the repro.obs.prof.phases catalog — core stays
-        # import-free of repro.obs
+        # names come from the repro.obs.prof.phases catalog — traced
+        # bodies never touch repro.obs (NDPP601/602)
         with jax.named_scope("ndpp.tree_descent"):
             if axis_name is None:
                 # unsharded hot path: the spec_round kernel on TPU

@@ -20,10 +20,15 @@ runtime statistics.  Design contract (enforced by tests and ndpplint):
 ``Telemetry`` bundles a ``MetricRegistry`` + ``FlightRecorder`` (+
 profiler gating) for the engine; ``RegistryObserver`` adapts the same
 registry to the duck-typed observer hooks on the batch samplers
-(``drive_rounds`` / ``sample_mcmc``).
+(``drive_rounds`` / ``sample_mcmc``).  Host time is measured by one
+primitive, ``span`` (``repro.obs.trace``); set-up, which runs before
+any ``Telemetry`` exists, records through ``setup_stage`` into the
+process-wide ``startup_registry()``.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import types
 from typing import Optional
 
@@ -39,17 +44,67 @@ from repro.obs.spans import Span, now
 from repro.obs.trace import (
     PHASE_PREFIX,
     PROFILE_ENV,
-    phase_annotation,
+    SETUP_PREFIX,
     profiling_enabled,
-    tick_annotation,
+    span,
 )
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "LogHistogram", "MetricRegistry",
     "FlightRecorder", "Span", "now", "Telemetry", "RegistryObserver",
-    "engine_instruments", "PROFILE_ENV", "PHASE_PREFIX",
-    "profiling_enabled", "tick_annotation", "phase_annotation",
+    "engine_instruments", "PROFILE_ENV", "PHASE_PREFIX", "SETUP_PREFIX",
+    "profiling_enabled", "span", "startup_registry", "setup_stage",
 ]
+
+_STARTUP: Optional[MetricRegistry] = None
+
+
+def startup_registry() -> MetricRegistry:
+    """The process-wide registry of the set-up counters.
+
+    Set-up runs before any ``Telemetry`` exists (``preprocess`` is called
+    bare), so its stages record here: ``ndpp_setup_seconds_total{stage}``
+    and ``ndpp_setup_compile_seconds_total{stage}``.  The front door's
+    ``/v1/metrics`` appends its exposition.
+    """
+    global _STARTUP
+    if _STARTUP is None:
+        _STARTUP = MetricRegistry()
+    return _STARTUP
+
+
+@contextlib.contextmanager
+def setup_stage(stage: str):
+    """Span of one set-up stage (``repro.obs.prof.phases.SETUP_STAGES``).
+
+    Adds the body's host seconds to ``ndpp_setup_seconds_total{stage}``,
+    and the seconds of the XLA compiles or persistent-cache loads inside
+    it (``CompileCounter.seconds``) to
+    ``ndpp_setup_compile_seconds_total``;
+    under ``NDPP_PROFILE=1`` the body is the annotation
+    ``ndpp_setup/<stage>``.  The caller blocks on the stage's outputs
+    inside the body, so the stage times the work, not its dispatch.
+    Eager host code only: never open a stage inside a traced body.
+    """
+    from repro.analysis.runtime import CompileCounter
+
+    reg = startup_registry()
+    secs = reg.counter("ndpp_setup_seconds_total",
+                       "host seconds per set-up stage", ("stage",))
+    comp = reg.counter("ndpp_setup_compile_seconds_total",
+                       "XLA backend-compile seconds inside each set-up "
+                       "stage (a persistent-cache load counts as its "
+                       "compile: JAX times both as one event)",
+                       ("stage",))
+    cc = CompileCounter.install()
+    c0 = cc.seconds
+
+    def record(seconds: float) -> None:
+        secs.inc(seconds, stage=stage)
+        comp.inc(cc.seconds - c0, stage=stage)
+
+    with span(SETUP_PREFIX + stage, record, profiling_enabled()):
+        yield
 
 
 def engine_instruments(registry: MetricRegistry) -> types.SimpleNamespace:
@@ -120,6 +175,10 @@ def engine_instruments(registry: MetricRegistry) -> types.SimpleNamespace:
                          "this is measured E[trials]; Theorem 2 bounds it "
                          "by 2^(K/2) for ONDPP kernels)", ("backend",),
                          start=1.0, factor=2 ** 0.5),
+        phase_seconds=c("ndpp_phase_seconds_total",
+                        "host seconds per engine tick phase (admission, "
+                        "round_dispatch, harvest: repro.obs.prof.phases)",
+                        ("backend", "phase")),
         ticks_held=h("ndpp_request_ticks_held",
                      "engine ticks a request occupied a slot",
                      ("backend",), start=1.0, factor=2.0),
@@ -137,8 +196,9 @@ class Telemetry:
       flight: flight recorder (default: fresh, ``flight_capacity`` events).
       dump_on_error: path the flight recorder is dumped to (JSONL) when
         the engine hits an error path (e.g. tick-budget exhaustion).
-      profile: wrap tick dispatch in ``jax.profiler.TraceAnnotation``
-        ranges; default reads ``NDPP_PROFILE=1`` once at construction.
+      profile: open a ``jax.profiler.TraceAnnotation`` with every
+        tick and phase span (``repro.obs.trace.span``); default reads
+        ``NDPP_PROFILE=1`` once at construction.
     """
 
     def __init__(self, registry: Optional[MetricRegistry] = None,
@@ -152,20 +212,6 @@ class Telemetry:
         self.dump_on_error = dump_on_error
         self.profile = (profiling_enabled() if profile is None
                         else bool(profile))
-
-    # host clock, re-exported so engine code never imports ``time``
-    now = staticmethod(now)
-
-    def profile_tick(self, name: str):
-        return tick_annotation(name, self.profile)
-
-    def phase(self, name: str):
-        """Profiler scope for one engine phase (``ndpp_phase/<name>``).
-
-        Phase names come from ``repro.obs.prof.phases``; a no-op unless
-        profiling was enabled at construction.
-        """
-        return phase_annotation(name, self.profile)
 
     def on_error(self) -> Optional[str]:
         """Dump the flight recorder to ``dump_on_error`` (if configured)."""
@@ -182,8 +228,8 @@ class RegistryObserver:
     ``core.dynamic.sample_dynamic_many``, ``core.mcmc.sample_mcmc``)
     accept an ``observer`` and call these hooks with plain Python numbers
     they already hold after their designed per-round ``device_get`` —
-    ``core`` stays import-free of ``repro.obs``, and the hooks never see
-    a traced value.
+    the traced bodies of ``core`` never touch ``repro.obs``, and the
+    hooks never see a traced value.
     """
 
     def __init__(self, registry: MetricRegistry, backend: str = "rejection"):
@@ -193,9 +239,13 @@ class RegistryObserver:
         self._profile = profiling_enabled()
 
     def phase(self, name: str):
-        """Profiler scope around a sampler phase (``drive_rounds`` uses
-        this duck-typed hook for its round-dispatch/harvest sections)."""
-        return phase_annotation(name, self._profile)
+        """Span around a sampler phase (``drive_rounds`` uses this
+        duck-typed hook for its round-dispatch/harvest sections): its
+        seconds go to ``ndpp_phase_seconds_total{backend, phase}``."""
+        return span(PHASE_PREFIX + name,
+                    functools.partial(self._m.phase_seconds.inc,
+                                      backend=self.backend, phase=name),
+                    self._profile)
 
     def on_round(self, *, n_active: int, n_spec: int, proposals: int,
                  accepts: int) -> None:

@@ -21,7 +21,8 @@ The optional HTTP adapter (``serve_http``) is a stdlib
 
   POST /v1/sample   {"seed": 7, "priority": 1, "deadline_in": 0.5, ...}
                     → 200 draw JSON | 503 shed | 400 bad request
-  GET  /v1/metrics  → Prometheus text exposition of the shared registry
+  GET  /v1/metrics  → Prometheus text exposition of the shared registry,
+                    then of ``repro.obs.startup_registry()`` (set-up)
   GET  /v1/stats    → scheduler + pool snapshot JSON
 
 Determinism note: none of this changes *what* is sampled — draws are
@@ -38,6 +39,8 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from repro.obs import startup_registry
+from repro.obs.prof import phases as prof_phases
 from repro.serve.sampler_engine import SampleResult
 from repro.serve.scheduler import (
     DuplicateRid,
@@ -171,17 +174,20 @@ class FrontDoor:
             if not self.scheduler.busy():
                 await asyncio.sleep(self.idle_interval)
                 continue
-            rep = self.scheduler.tick()
-            for rid, res in rep.retired.items():
-                fut = self._futures.get(rid)
-                if fut is not None and not fut.done():
-                    fut.set_result(res)
-            for out in rep.shed:
-                fut = self._futures.get(out.rid)
-                if fut is not None and not fut.done():
-                    fut.set_exception(ShedError(out))
+            sched = self.scheduler
+            rep = sched.tick()
+            with sched.phase(prof_phases.RESOLVE):
+                for rid, res in rep.retired.items():
+                    fut = self._futures.get(rid)
+                    if fut is not None and not fut.done():
+                        fut.set_result(res)
+                for out in rep.shed:
+                    fut = self._futures.get(out.rid)
+                    if fut is not None and not fut.done():
+                        fut.set_exception(ShedError(out))
             # yield so submitters interleave with ticks even under load
-            await asyncio.sleep(0)
+            with sched.phase(prof_phases.YIELD):
+                await asyncio.sleep(0)
 
 
 # --------------------------------------------------------------- HTTP front
@@ -222,8 +228,9 @@ class _FrontDoorHandler(BaseHTTPRequestHandler):
             if tel is None:
                 self._reply_json(404, {"error": "no telemetry attached"})
                 return
-            self._reply(200, tel.registry.expose().encode(),
-                        "text/plain; version=0.0.4")
+            # the set-up counters live in the process-wide registry
+            text = tel.registry.expose() + startup_registry().expose()
+            self._reply(200, text.encode(), "text/plain; version=0.0.4")
         elif self.path == "/v1/stats":
             self._reply_json(200, door.scheduler.stats())
         else:

@@ -66,13 +66,24 @@ from repro.core.rejection import (
 )
 from repro.core.tree import shard_spectral
 from repro.core.types import SpectralNDPP
-from repro.obs import Span, Telemetry, engine_instruments
+from repro.obs import (
+    PHASE_PREFIX,
+    Span,
+    Telemetry,
+    engine_instruments,
+    setup_stage,
+)
+from repro.obs import span as obs_span
 from repro.obs.prof import NULL_ACCOUNTANT, Accountant
 from repro.obs.prof import phases as prof_phases
 from repro.serve.catalog import Catalog, CatalogState, as_state
 
 #: shared no-op context for the uninstrumented engine's phase scopes
 _NULL_PHASE = contextlib.nullcontext()
+
+
+def _discard(seconds: float) -> None:
+    """Span recorder of a tick with nothing to advance."""
 
 
 class TickBudgetExhausted(RuntimeError):
@@ -255,113 +266,133 @@ class SamplerEngine:
             raise ValueError(f"unknown backend {backend!r}")
         if on_exhausted not in ("raise", "warn", "ignore"):
             raise ValueError(f"unknown on_exhausted mode {on_exhausted!r}")
-        self.on_exhausted = on_exhausted
-        self.backend = backend
-        self.mesh = mesh
-        self._cat: Optional[CatalogState] = None
-        if isinstance(sampler, (Catalog, CatalogState)):
-            # dynamic-catalog mode: the catalog owns preprocessing, mesh
-            # placement, and versioning; each request pins the CatalogState
-            # current at admission, so swap_catalog never drains the pool
-            if isinstance(sampler, Catalog):
-                if mesh is not None and sampler.mesh is not mesh:
+        # set-up stage engine_init/<backend>: placement, auto n_spec's
+        # det_ratio_exact, the MCMC pool state — blocked on before it closes
+        with setup_stage(f"{prof_phases.ENGINE_INIT}/{backend}"):
+            self.on_exhausted = on_exhausted
+            self.backend = backend
+            self.mesh = mesh
+            self._cat: Optional[CatalogState] = None
+            if isinstance(sampler, (Catalog, CatalogState)):
+                # dynamic-catalog mode: the catalog owns preprocessing,
+                # mesh placement, and versioning; each request pins the
+                # CatalogState current at admission, so swap_catalog
+                # never drains the pool
+                if isinstance(sampler, Catalog):
+                    if mesh is not None and sampler.mesh is not mesh:
+                        raise ValueError(
+                            "pass the catalog's own mesh (or none) — the "
+                            "catalog arrays are already placed on it")
+                    self.mesh = mesh = sampler.mesh
+                self._cat = as_state(sampler)
+                self.sampler = None
+                self.sp = self._cat.sp
+            elif isinstance(sampler, NDPPSampler):
+                self.sampler: Optional[NDPPSampler] = sampler
+                self.sp = sampler.sp
+            else:
+                if backend == "rejection":
                     raise ValueError(
-                        "pass the catalog's own mesh (or none) — the "
-                        "catalog arrays are already placed on it")
-                self.mesh = mesh = sampler.mesh
-            self._cat = as_state(sampler)
-            self.sampler = None
-            self.sp = self._cat.sp
-        elif isinstance(sampler, NDPPSampler):
-            self.sampler: Optional[NDPPSampler] = sampler
-            self.sp = sampler.sp
-        else:
+                        "backend='rejection' needs a preprocessed "
+                        "NDPPSampler or a Catalog/CatalogState")
+                self.sampler = None
+                self.sp = sampler
+            if mesh is not None and self._cat is None:
+                from repro.models.sharding import model_extent
+
+                s = model_extent(mesh)
+                if self.sp.M % s != 0:
+                    raise ValueError(
+                        f"the mesh 'model' extent {s} must divide the "
+                        f"catalog size M={self.sp.M} — pad the catalog or "
+                        f"shrink the mesh")
+                if self.sampler is not None:
+                    tree = self.sampler.tree
+                    if tree.W.shape[0] % (s * tree.block) != 0:
+                        # a "sharded" engine that silently replicates the
+                        # tree (the dominant memory) is a config bug, not a
+                        # fallback
+                        raise ValueError(
+                            f"cannot shard the proposal tree: each shard "
+                            f"must own whole leaf blocks, i.e. {s} * block="
+                            f"{tree.block} must divide M_pad="
+                            f"{tree.W.shape[0]} — use a smaller block or "
+                            f"shrink the mesh")
+                    self.sampler = shard_sampler(self.sampler, mesh)
+                    self.sp = self.sampler.sp
+                else:
+                    self.sp = shard_spectral(self.sp, mesh)
+            self.n_slots = n_slots
             if backend == "rejection":
-                raise ValueError(
-                    "backend='rejection' needs a preprocessed NDPPSampler "
-                    "or a Catalog/CatalogState")
-            self.sampler = None
-            self.sp = sampler
-        if mesh is not None and self._cat is None:
-            from repro.models.sharding import model_extent
-
-            s = model_extent(mesh)
-            if self.sp.M % s != 0:
-                raise ValueError(
-                    f"the mesh 'model' extent {s} must divide the catalog "
-                    f"size M={self.sp.M} — pad the catalog or shrink the "
-                    f"mesh")
-            if self.sampler is not None:
-                tree = self.sampler.tree
-                if tree.W.shape[0] % (s * tree.block) != 0:
-                    # a "sharded" engine that silently replicates the tree
-                    # (the dominant memory) is a config bug, not a fallback
-                    raise ValueError(
-                        f"cannot shard the proposal tree: each shard must "
-                        f"own whole leaf blocks, i.e. {s} * block="
-                        f"{tree.block} must divide M_pad={tree.W.shape[0]} "
-                        f"— use a smaller block or shrink the mesh")
-                self.sampler = shard_sampler(self.sampler, mesh)
-                self.sp = self.sampler.sp
+                # default the speculation depth to ~E[#trials] so most
+                # requests retire after a single tick
+                self._auto_spec = n_spec is None
+                if n_spec is not None:
+                    self.n_spec = n_spec
+                elif self._cat is not None:
+                    self.n_spec = auto_n_spec_dynamic(self._cat.proposal,
+                                                      self._cat.sp)
+                else:
+                    self.n_spec = auto_n_spec(sampler)
             else:
-                self.sp = shard_spectral(self.sp, mesh)
-        self.n_slots = n_slots
-        if backend == "rejection":
-            # default the speculation depth to ~E[#trials] so most requests
-            # retire after a single tick
-            self._auto_spec = n_spec is None
-            if n_spec is not None:
-                self.n_spec = n_spec
-            elif self._cat is not None:
-                self.n_spec = auto_n_spec_dynamic(self._cat.proposal,
-                                                  self._cat.sp)
-            else:
-                self.n_spec = auto_n_spec(sampler)
-        else:
-            self.mcmc_burn_in = mcmc_burn_in
-            self.mcmc_thin = mcmc_thin
-            self.mcmc_k = mcmc_k
-            self.mcmc_p_swap = mcmc_p_swap
-            self.mcmc_refresh_every = mcmc_refresh_every
-            self.mcmc_steps_per_tick = (
-                min(mcmc_refresh_every, mcmc_burn_in + mcmc_thin)
-                if mcmc_steps_per_tick is None else mcmc_steps_per_tick)
-            init = mcmc_core.init_empty(self.sp)
-            self._states = jax.tree_util.tree_map(
-                lambda a: jnp.broadcast_to(a, (n_slots,) + a.shape), init)
-        self.queue: List[SampleRequest] = []
-        self.slot_req: List[Optional[SampleRequest]] = [None] * n_slots
-        self.slot_key = np.zeros((n_slots, _prng_key_words()), np.uint32)
-        self.slot_trials = np.zeros(n_slots, np.int64)
-        # catalog mode: the CatalogState each in-flight request samples
-        # from — pinned at admission, released at retire
-        self.slot_pin: List[Optional[CatalogState]] = [None] * n_slots
-        self.finished: Dict[int, SampleResult] = {}
-        self.ticks = 0
-        self._tel = telemetry
-        self._spans: Dict[int, Span] = {}
-        # every jitted call / put / designed device_get goes through the
-        # accountant, so dispatch and transfer counts are exact at the
-        # call boundary (repro.obs.prof.accounting); the bare engine gets
-        # the straight-through null twin
-        self._acct = NULL_ACCOUNTANT
-        if telemetry is not None:
-            self._m = engine_instruments(telemetry.registry)
-            self._acct = Accountant(backend, instruments=self._m)
-            # compile visibility: poll the process-wide CompileCounter
-            # after each tick so unexpected recompiles show up as a
-            # counter bump + flight event instead of silent latency
-            from repro.analysis.runtime import CompileCounter
+                self.mcmc_burn_in = mcmc_burn_in
+                self.mcmc_thin = mcmc_thin
+                self.mcmc_k = mcmc_k
+                self.mcmc_p_swap = mcmc_p_swap
+                self.mcmc_refresh_every = mcmc_refresh_every
+                self.mcmc_steps_per_tick = (
+                    min(mcmc_refresh_every, mcmc_burn_in + mcmc_thin)
+                    if mcmc_steps_per_tick is None else mcmc_steps_per_tick)
+                init = mcmc_core.init_empty(self.sp)
+                self._states = jax.tree_util.tree_map(
+                    lambda a: jnp.broadcast_to(a, (n_slots,) + a.shape),
+                    init)
+            self.queue: List[SampleRequest] = []
+            self.slot_req: List[Optional[SampleRequest]] = [None] * n_slots
+            self.slot_key = np.zeros((n_slots, _prng_key_words()), np.uint32)
+            self.slot_trials = np.zeros(n_slots, np.int64)
+            # catalog mode: the CatalogState each in-flight request samples
+            # from — pinned at admission, released at retire
+            self.slot_pin: List[Optional[CatalogState]] = [None] * n_slots
+            self.finished: Dict[int, SampleResult] = {}
+            self.ticks = 0
+            # set once the first tick that progresses has run (its own
+            # set-up stage: the tick program's compile or cache load)
+            self._ticked = False
+            self._tel = telemetry
+            self._spans: Dict[int, Span] = {}
+            # every jitted call / put / designed device_get goes through
+            # the accountant, so dispatch and transfer counts are exact at
+            # the call boundary (repro.obs.prof.accounting); the bare
+            # engine gets the straight-through null twin
+            self._acct = NULL_ACCOUNTANT
+            if telemetry is not None:
+                self._m = engine_instruments(telemetry.registry)
+                self._acct = Accountant(backend, instruments=self._m)
+                # span recorders, built once: the tick's wall seconds and
+                # each phase's host seconds
+                self._tick_rec = functools.partial(
+                    self._m.tick_seconds.observe, backend=backend)
+                self._phase_rec = {
+                    p: functools.partial(self._m.phase_seconds.inc,
+                                         backend=backend, phase=p)
+                    for p in prof_phases.ENGINE_PHASES}
+                # compile visibility: poll the process-wide CompileCounter
+                # after each tick so unexpected recompiles show up as a
+                # counter bump + flight event instead of silent latency
+                from repro.analysis.runtime import CompileCounter
 
-            self._cc = CompileCounter.install()
-            self._cc_seen = self._cc.count
-            telemetry.flight.record(
-                "engine_start", backend=backend, n_slots=n_slots,
-                n_spec=getattr(self, "n_spec", None),
-                catalog_version=None if self._cat is None
-                else self._cat.version)
-            if self._cat is not None:
-                self._m.catalog_version.set(self._cat.version)
+                self._cc = CompileCounter.install()
+                self._cc_seen = self._cc.count
+                telemetry.flight.record(
+                    "engine_start", backend=backend, n_slots=n_slots,
+                    n_spec=getattr(self, "n_spec", None),
+                    catalog_version=None if self._cat is None
+                    else self._cat.version)
+                if self._cat is not None:
+                    self._m.catalog_version.set(self._cat.version)
+            jax.block_until_ready((self.sampler, self.sp,
+                                   getattr(self, "_states", None)))
 
     # ------------------------------------------------------------- frontend
     def submit(self, req: SampleRequest, span: Optional[Span] = None):
@@ -442,9 +473,14 @@ class SamplerEngine:
             self.n_spec = auto_n_spec_dynamic(st.proposal, st.sp)
 
     def _phase(self, name: str):
-        """Profiler scope for one engine phase (no-op without telemetry
-        or with ``NDPP_PROFILE`` unset)."""
-        return self._tel.phase(name) if self._tel is not None else _NULL_PHASE
+        """Span of one engine phase: its host seconds go to
+        ``ndpp_phase_seconds_total{backend, phase}``, and under
+        ``NDPP_PROFILE=1`` it is the annotation ``ndpp_phase/<name>``
+        (a no-op without telemetry)."""
+        if self._tel is None:
+            return _NULL_PHASE
+        return obs_span(PHASE_PREFIX + name, self._phase_rec[name],
+                        self._tel.profile)
 
     def _init_chain_state(self, seed: int) -> mcmc_core.MCMCState:
         """Deterministic per-request chain start (schedule-independent):
@@ -511,18 +547,27 @@ class SamplerEngine:
     def step(self) -> bool:
         """One engine tick: admit from queue, advance the whole pool with
         one jitted fixed-shape call, retire finished slots."""
+        # the tick progresses iff it has work: admission fills a free slot
+        # whenever the queue holds a request
+        busy = bool(self.queue) or any(r is not None for r in self.slot_req)
+        if busy and not self._ticked:
+            self._ticked = True
+            with setup_stage(f"{prof_phases.FIRST_TICK}/{self.backend}"):
+                return self._tick(busy)
+        return self._tick(busy)
+
+    def _tick(self, busy: bool) -> bool:
+        advance = (self._step_mcmc if self.backend == "mcmc"
+                   else self._step_rejection)
         if self._tel is None:
-            if self.backend == "mcmc":
-                return self._step_mcmc()
-            return self._step_rejection()
-        t0 = self._tel.now()
-        with self._tel.profile_tick(f"ndpp_engine_tick/{self.backend}"):
-            progressed = (self._step_mcmc() if self.backend == "mcmc"
-                          else self._step_rejection())
+            return advance()
+        # a tick with work feeds ndpp_tick_seconds (tick_ms.rej reads it)
+        with obs_span(f"ndpp_engine_tick/{self.backend}",
+                      self._tick_rec if busy else _discard,
+                      self._tel.profile):
+            progressed = advance()
         if progressed:
             self._m.ticks.inc(backend=self.backend)
-            self._m.tick_seconds.observe(self._tel.now() - t0,
-                                         backend=self.backend)
         self._m.slots_occupied.set(
             sum(r is not None for r in self.slot_req))
         self._m.queue_depth.set(len(self.queue))

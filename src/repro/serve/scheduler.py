@@ -42,7 +42,9 @@ with.  The scheduler opens each request's span at *its* submission point
 and hands it down to the engine at staging, so the engine's
 submit→admit/submit→retire histograms measure the true front-door wait;
 scheduler-level decisions (shed, evict, autoscale) stream into its own
-``ndpp_sched_*`` instruments in the same registry.  When telemetry is
+``ndpp_sched_*`` instruments in the same registry, and the host seconds
+of its admission phase and of the front door's phases into
+``ndpp_sched_phase_seconds_total`` (``Scheduler.phase``).  When telemetry is
 enabled, the queue-wait p99 over a sliding window can drive the
 rejection pools' speculation depth (``autoscale_n_spec=True``): n_spec
 doubles while waits exceed ``target_queue_wait`` and halves when the
@@ -51,13 +53,23 @@ sees a handful of shapes, each compiled once.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import heapq
 import math
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
-from repro.obs import LogHistogram, MetricRegistry, Span, Telemetry
+from repro.obs import (
+    PHASE_PREFIX,
+    LogHistogram,
+    MetricRegistry,
+    Span,
+    Telemetry,
+)
 from repro.obs import now as _obs_now
+from repro.obs import span as obs_span
+from repro.obs.prof import phases as prof_phases
 from repro.serve.sampler_engine import (
     SampleRequest,
     SampleResult,
@@ -66,6 +78,9 @@ from repro.serve.sampler_engine import (
 )
 
 SHED_REASONS = ("deadline", "queue_full", "evicted")
+
+#: shared no-op context for phases of a scheduler without telemetry
+_NULL_PHASE = contextlib.nullcontext()
 
 
 class DuplicateRid(ValueError):
@@ -151,6 +166,10 @@ def sched_instruments(registry: MetricRegistry):
         n_spec=g("ndpp_sched_n_spec",
                  "current speculation depth of a rejection pool",
                  ("pool",)),
+        phase_seconds=c("ndpp_sched_phase_seconds_total",
+                        "host seconds per scheduler / front-door phase "
+                        "(sched_admission, resolve, yield: "
+                        "repro.obs.prof.phases)", ("phase",)),
         queue_wait=h("ndpp_sched_queue_wait_seconds",
                      "scheduler-clock seconds from submit to staging "
                      "(admitted requests only — sheds never pollute this)",
@@ -229,6 +248,9 @@ class Scheduler:
         self._win = LogHistogram(start=1e-5, factor=2 ** 0.25)
         if self._tel is not None:
             self._m = sched_instruments(self._tel.registry)
+            self._phase_rec = {
+                p: functools.partial(self._m.phase_seconds.inc, phase=p)
+                for p in prof_phases.SCHED_PHASES}
             for name, eng in sorted(self.pools.items()):
                 if eng.backend == "rejection":
                     self._m.n_spec.set(eng.n_spec, pool=name)
@@ -302,6 +324,18 @@ class Scheduler:
         engine's zero-drain semantics are unchanged)."""
         self.pools[pool].swap_catalog(cat)
 
+    def phase(self, name: str):
+        """Span of one scheduler or front-door phase
+        (``repro.obs.prof.phases.SCHED_PHASES``): its host seconds go to
+        ``ndpp_sched_phase_seconds_total{phase}``, and under
+        ``NDPP_PROFILE=1`` it is the annotation ``ndpp_phase/<name>``
+        (a no-op without telemetry).  No phase here blocks on the
+        device (ndpplint NDPP701)."""
+        if self._tel is None:
+            return _NULL_PHASE
+        return obs_span(PHASE_PREFIX + name, self._phase_rec[name],
+                        self._tel.profile)
+
     # ----------------------------------------------------------------- core
     def tick(self) -> TickReport:
         """One scheduler tick: shed/admit from the priority queue into
@@ -311,30 +345,32 @@ class Scheduler:
         self.ticks += 1
         shed: List[Outcome] = []
         admitted: List[Tuple[int, str]] = []
-        free = {}
-        for name in self._pool_names:
-            eng = self.pools[name]
-            free[name] = (sum(r is None for r in eng.slot_req)
-                          - len(eng.queue))
-        # admission: pop best-first; expired requests shed at their turn,
-        # requests for a full specific pool are held back for this tick
-        holdback = []
-        while self._heap and any(f > 0 for f in free.values()):
-            key, req = heapq.heappop(self._heap)
-            if self._known.get(req.rid) != "queued":
-                continue  # cancelled while queued — entry removed lazily
-            if req.deadline is not None and t_now > req.deadline:
-                shed.append(self._shed(req, "deadline"))
-                continue
-            name = self._route(req, free)
-            if name is None:
-                holdback.append((key, req))
-                continue
-            free[name] -= 1
-            admitted.append((req.rid, name))
-            self._stage(req, name, t_now)
-        for entry in holdback:
-            heapq.heappush(self._heap, entry)
+        with self.phase(prof_phases.SCHED_ADMISSION):
+            free = {}
+            for name in self._pool_names:
+                eng = self.pools[name]
+                free[name] = (sum(r is None for r in eng.slot_req)
+                              - len(eng.queue))
+            # admission: pop best-first; expired requests shed at their
+            # turn, requests for a full specific pool are held back for
+            # this tick
+            holdback = []
+            while self._heap and any(f > 0 for f in free.values()):
+                key, req = heapq.heappop(self._heap)
+                if self._known.get(req.rid) != "queued":
+                    continue  # cancelled while queued — removed lazily
+                if req.deadline is not None and t_now > req.deadline:
+                    shed.append(self._shed(req, "deadline"))
+                    continue
+                name = self._route(req, free)
+                if name is None:
+                    holdback.append((key, req))
+                    continue
+                free[name] -= 1
+                admitted.append((req.rid, name))
+                self._stage(req, name, t_now)
+            for entry in holdback:
+                heapq.heappush(self._heap, entry)
         # advance: every pool with work steps once; slots freed by these
         # retires are refilled at the next tick's admission phase
         retired: Dict[int, SampleResult] = {}

@@ -426,9 +426,254 @@ def test_profile_gate_defaults_off(monkeypatch):
     assert Telemetry().profile is False
     monkeypatch.setenv(trace.PROFILE_ENV, "1")
     assert Telemetry().profile is True
-    # disabled annotations are a shared no-op object — no profiler import
-    tel = Telemetry(profile=False)
-    cm = tel.profile_tick("tick/rejection")
-    with cm:
+    # a disabled span builds no annotation and never reaches the
+    # profiler: it wraps the shared no-op object, and still records
+    def no_profiler(name, enabled):
+        raise AssertionError("a disabled span built an annotation")
+
+    monkeypatch.setattr(trace, "annotation", no_profiler)
+    got = []
+    sp = trace.span("ndpp_engine_tick/rejection", got.append, False)
+    with sp:
         pass
-    assert cm is tel.profile_tick("tick/other")
+    assert sp._ann is trace._NULL
+    assert len(got) == 1 and got[0] >= 0.0
+
+
+# ------------------------------------------------------- spans and set-up
+def _setup(stage):
+    """(host seconds, compile seconds) a set-up stage has recorded."""
+    from repro.obs import startup_registry
+
+    reg = startup_registry()
+    return (reg.counter("ndpp_setup_seconds_total", labels=("stage",))
+            .value(stage=stage),
+            reg.counter("ndpp_setup_compile_seconds_total",
+                        labels=("stage",)).value(stage=stage))
+
+
+def test_span_records_host_seconds_even_on_error():
+    from repro.obs import now, span
+
+    got = []
+    t0 = now()
+    with span("ndpp_phase/harvest", got.append, False):
+        inner = now()
+    assert 0.0 <= got[0] <= now() - t0 and inner >= t0
+    with pytest.raises(RuntimeError):
+        with span("ndpp_phase/harvest", got.append, False):
+            raise RuntimeError("boom")
+    assert len(got) == 2 and got[1] >= 0.0
+
+
+def test_compile_counter_sums_compile_seconds():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.analysis.runtime import CompileCounter
+
+    cc = CompileCounter.install()
+    n0, s0 = cc.count, cc.seconds
+    f = jax.jit(lambda x: jnp.sin(x) * 3.0 + 0.125)
+    jax.block_until_ready(f(np.ones(7, np.float32)))
+    assert cc.count == n0 + 1 and cc.seconds > s0
+    n1, s1 = cc.count, cc.seconds
+    jax.block_until_ready(f(np.ones(7, np.float32)))
+    assert (cc.count, cc.seconds) == (n1, s1)
+
+
+def test_preprocess_records_setup_stages():
+    import jax
+
+    r = np.random.default_rng(5)
+    m, k = 40, 4
+    v = (r.normal(size=(m, k)) * 0.6).astype(np.float32)
+    b = (r.normal(size=(m, k)) * 0.6).astype(np.float32)
+    d = r.normal(size=(k, k)).astype(np.float32)
+    stages = ("youla", "proposal_eigens", "tree_build")
+    before = {st: _setup(st) for st in stages}
+    # leak checking (the strict leg) recompiles every eager op
+    with jax.check_tracer_leaks(False):
+        preprocess(v, b, d, block=4)
+        mid = {st: _setup(st) for st in stages}
+        preprocess(v, b, d, block=4)  # same shapes: every program cached
+    for st in stages:
+        assert mid[st][0] > before[st][0], st
+    for st in stages:
+        secs, comp = _setup(st)
+        assert secs > mid[st][0], st
+        assert comp == mid[st][1], st
+
+
+@pytest.mark.parametrize("backend", ["rejection", "mcmc"])
+def test_engine_phase_spans_within_tick(sampler, backend):
+    """Per engine tick: each phase is entered once, and the phases'
+    host seconds sum to at most the tick's seconds."""
+    from repro.obs import engine_instruments
+    from repro.obs.prof import phases as ph
+
+    tel = Telemetry()
+    m = engine_instruments(tel.registry)
+    entries = []
+    inc = m.phase_seconds.inc
+
+    def counting_inc(v=1.0, **labels):
+        entries.append(labels["phase"])
+        inc(v, **labels)
+
+    m.phase_seconds.inc = counting_inc
+    kw = (dict(backend="mcmc", mcmc_burn_in=16, mcmc_thin=8,
+               mcmc_steps_per_tick=8) if backend == "mcmc" else {})
+    eng = SamplerEngine(sampler, n_slots=3, telemetry=tel, **kw)
+    first = _setup(f"first_tick/{backend}")[0]
+    for i in range(5):
+        eng.submit(SampleRequest(rid=i, seed=i, max_trials=200))
+    tick_h = tel.registry.get("ndpp_tick_seconds")
+    ticks = 0
+    while True:
+        n0 = len(entries)
+        t0 = tick_h.data(backend=backend).total
+        p0 = m.phase_seconds.total()
+        if not eng.step():
+            break
+        ticks += 1
+        assert sorted(entries[n0:]) == sorted(ph.ENGINE_PHASES)
+        tick_s = tick_h.data(backend=backend).total - t0
+        assert 0.0 < m.phase_seconds.total() - p0 <= tick_s
+    assert ticks > 1 and eng.ticks == ticks
+    assert m.ticks.value(backend=backend) == ticks
+    assert tick_h.data(backend=backend).count == ticks
+    for p in ph.ENGINE_PHASES:
+        assert m.phase_seconds.value(backend=backend, phase=p) > 0.0
+    # the engine's first tick that progressed is its own set-up stage
+    assert _setup(f"first_tick/{backend}")[0] > first
+
+
+def test_frontdoor_feeds_sched_phase_seconds(sampler):
+    import asyncio
+
+    from repro.obs.prof import phases as ph
+    from repro.serve.frontdoor import FrontDoor
+    from repro.serve.scheduler import Scheduler
+
+    tel = Telemetry()
+
+    async def main():
+        pools = {"rej": SamplerEngine(sampler, n_slots=2, n_spec=4,
+                                      telemetry=tel)}
+        async with FrontDoor(Scheduler(pools, telemetry=tel),
+                             idle_interval=0.001) as door:
+            await asyncio.gather(*(door.sample(s, rid=s, max_trials=400)
+                                   for s in range(5)))
+
+    asyncio.run(main())
+    c = tel.registry.get("ndpp_sched_phase_seconds_total")
+    assert sorted(k[0] for k, _ in c.labelsets()) == sorted(ph.SCHED_PHASES)
+    for p in ph.SCHED_PHASES:
+        assert c.value(phase=p) > 0.0, p
+
+
+def test_profile_capture_holds_setup_phase_and_tick_events(
+        monkeypatch, tmp_path):
+    """Under NDPP_PROFILE=1 every span is also a host annotation in a
+    real (CPU) profiler capture."""
+    import asyncio
+
+    from repro.obs import trace
+    from repro.obs.prof import capture as cap
+    from repro.obs.prof import complete_events, load_trace
+    from repro.obs.prof import phases as ph
+    from repro.serve.frontdoor import FrontDoor
+    from repro.serve.scheduler import Scheduler
+
+    monkeypatch.setenv(trace.PROFILE_ENV, "1")
+    r = np.random.default_rng(9)
+    v = (r.normal(size=(M, K)) * 0.6).astype(np.float32)
+    b = (r.normal(size=(M, K)) * 0.6).astype(np.float32)
+    d = r.normal(size=(K, K)).astype(np.float32)
+
+    async def serve(smp):
+        tel = Telemetry()
+        pools = {"rej": SamplerEngine(smp, n_slots=2, n_spec=4,
+                                      telemetry=tel)}
+        async with FrontDoor(Scheduler(pools, telemetry=tel),
+                             idle_interval=0.001) as door:
+            await asyncio.gather(*(door.sample(s, rid=s, max_trials=400)
+                                   for s in range(3)))
+
+    log_dir = str(tmp_path / "prof")
+    try:
+        with cap.capture(log_dir):
+            asyncio.run(serve(preprocess(v, b, d, block=4)))
+    except cap.ProfilerUnavailable as e:
+        pytest.skip(f"profiler not available here: {e}")
+    names = {e["name"] for e in complete_events(
+        load_trace(cap.trace_path(log_dir)))}
+    want = {trace.SETUP_PREFIX + st for st in (
+        "youla", "proposal_eigens", "tree_build", "engine_init/rejection",
+        "first_tick/rejection")}
+    want |= {trace.PHASE_PREFIX + p for p in ph.HOST_PHASES}
+    want.add("ndpp_engine_tick/rejection")
+    assert want <= names, sorted(want - names)
+
+
+def test_lowered_round_carries_descent_operands_scope(monkeypatch):
+    """The per-round flattening of the levels into the descent kernel's
+    layout is its own device scope (kernel path, interpreted here)."""
+    import jax.numpy as jnp
+
+    from repro.core.rejection import _spec_round_fused
+    from repro.obs.prof import phases as ph
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    r = np.random.default_rng(13)
+    m, k = 56, 4                       # shapes no other test lowers
+    smp = preprocess(jnp.asarray(r.normal(size=(m, k)) * 0.5, jnp.float32),
+                     jnp.asarray(r.normal(size=(m, k)) * 0.5, jnp.float32),
+                     jnp.asarray(r.normal(size=(k, k)), jnp.float32),
+                     block=4)
+    keys = np.zeros((3, 2), np.uint32)
+    trials = np.zeros(3, np.uint32)
+    text = _spec_round_fused.lower(smp, keys, trials, n_spec=5).as_text(
+        debug_info=True)
+    assert ph.DESCENT_OPERANDS in ph.DEVICE_SCOPES
+    assert ph.DESCENT_OPERANDS + "/" in text
+    assert ph.TREE_DESCENT in text
+
+
+def test_v1_metrics_exposes_setup_counters(sampler):
+    import asyncio
+    import threading
+    import urllib.request
+
+    from repro.serve.frontdoor import FrontDoor, serve_http
+    from repro.serve.scheduler import Scheduler
+
+    async def main():
+        tel = Telemetry()
+        pools = {"rej": SamplerEngine(sampler, n_slots=2, n_spec=4,
+                                      telemetry=tel)}
+        async with FrontDoor(Scheduler(pools, telemetry=tel),
+                             idle_interval=0.001) as door:
+            await door.sample(3, rid=1, max_trials=400)
+            srv = serve_http(door, asyncio.get_running_loop())
+            thread = threading.Thread(target=srv.serve_forever, daemon=True)
+            thread.start()
+            host, port = srv.server_address
+
+            def get():
+                with urllib.request.urlopen(
+                        f"http://{host}:{port}/v1/metrics", timeout=30) as r:
+                    return r.status, r.read().decode()
+
+            try:
+                return await asyncio.get_running_loop().run_in_executor(
+                    None, get)
+            finally:
+                srv.shutdown()
+
+    code, text = asyncio.run(main())
+    assert code == 200
+    assert "ndpp_sched_phase_seconds_total" in text
+    assert 'ndpp_setup_seconds_total{stage="engine_init/rejection"}' in text
+    assert "# TYPE ndpp_setup_compile_seconds_total counter" in text

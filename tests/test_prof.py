@@ -272,7 +272,9 @@ def test_phase_catalog_matches_lint_contract():
     # NDPP701's sanctioned-phase set is a literal copy of this frozenset;
     # if the catalog grows a second sanctioned phase, both must move
     assert ph.BLOCKING_ALLOWED == frozenset({"harvest"})
-    assert set(ph.HOST_PHASES) == {"admission", "round_dispatch", "harvest"}
+    assert set(ph.HOST_PHASES) == {"admission", "round_dispatch", "harvest",
+                                   "sched_admission", "resolve", "yield"}
+    assert set(ph.ENGINE_PHASES) | set(ph.SCHED_PHASES) == set(ph.HOST_PHASES)
 
 
 # ------------------------------------------------------------------- schema
