@@ -82,12 +82,12 @@ def preprocess(V: jax.Array, B: jax.Array, D: jax.Array, block: int = 64) -> NDP
     from repro.obs import setup_stage
     from repro.obs.prof import phases
 
-    from .youla import spectral_from_params
+    from .youla import spectral_and_gram
 
     with setup_stage(phases.YOULA):
-        sp = jax.block_until_ready(spectral_from_params(V, B, D))
+        sp, gram = jax.block_until_ready(spectral_and_gram(V, B, D))
     with setup_stage(phases.PROPOSAL_EIGENS):
-        lam, w = jax.block_until_ready(proposal_eigens(sp))
+        lam, w = jax.block_until_ready(proposal_eigens(sp, gram=gram))
     with setup_stage(phases.TREE_BUILD):
         tree = jax.block_until_ready(construct_tree(lam, w, block=block))
     return NDPPSampler(sp=sp, tree=tree)

@@ -23,6 +23,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.kernels.bilinear import ops as bilinear_ops
@@ -37,16 +38,29 @@ from .types import SpectralNDPP
 _SHALLOW_MAX = 32
 
 
-def proposal_eigens(sp: SpectralNDPP, eps: float = 1e-10) -> Tuple[jax.Array, jax.Array]:
+def proposal_eigens(sp: SpectralNDPP, eps: float = 1e-10,
+                    gram: Optional[np.ndarray] = None
+                    ) -> Tuple[jax.Array, jax.Array]:
     """Eigendecomposition of Lhat = A A^T via the 2K x 2K Gram of A = Z Xhat^{1/2}.
 
     Returns (lam, W): lam (2K,) eigenvalues (>= 0, zeros for the null space),
     W (M, 2K) orthonormal eigenvector columns (zero columns where lam == 0).
+
+    ``gram``: ``A^T A`` in float64 (``youla.spectral_and_gram``), whose
+    eigendecomposition is then taken in float64 on the host.  Lhat's
+    eigenvalues come in near-degenerate pairs (a Youla pair
+    ``sigma_j (y1 y1^T + y2 y2^T)``, split only by V V^T), some within a
+    few float32 ulps of each other.  A float32 Gram fixes no basis inside
+    such a pair: its eigenvectors come out rotated by up to several
+    degrees, and a proposal that keeps one of the pair then descends the
+    tree of another elementary DPP than Lhat's own eigenvectors give.
     """
     xhalf = jnp.sqrt(sp.x_diag_hat())
     a = sp.Z * xhalf[None, :]
-    g = a.T @ a
-    lam, u = jnp.linalg.eigh(g)
+    if gram is None:
+        lam, u = jnp.linalg.eigh(a.T @ a)
+    else:
+        lam, u = (jnp.asarray(x, a.dtype) for x in np.linalg.eigh(gram))
     lam = jnp.maximum(lam, 0.0)
     good = lam > eps
     denom = jnp.where(good, jnp.sqrt(jnp.maximum(lam, eps)), 1.0)

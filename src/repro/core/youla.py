@@ -75,6 +75,22 @@ def spectral_from_params(V: jax.Array, B: jax.Array, D: jax.Array):
     return SpectralNDPP(Z=z, sigma=sig)
 
 
+def spectral_and_gram(V: jax.Array, B: jax.Array, D: jax.Array):
+    """``spectral_from_params``, plus the 2K x 2K Gram ``A^T A`` of the
+    proposal factor ``A = Z Xhat^{1/2}`` (``Lhat = A A^T``) in float64 on
+    the host, formed from the float64 Youla factors before Z is rounded
+    to V's dtype (``tree.proposal_eigens`` takes it)."""
+    from .types import SpectralNDPP
+
+    sig, y = youla_decompose_np(np.asarray(B), np.asarray(D))
+    sp = SpectralNDPP(Z=jnp.concatenate([V, jnp.asarray(y, B.dtype)], axis=1),
+                      sigma=jnp.asarray(sig, B.dtype))
+    v = np.asarray(V, np.float64)
+    a = np.concatenate([v, y], axis=1) * np.sqrt(np.concatenate(
+        [np.ones(v.shape[1]), np.repeat(sig, 2)]))
+    return sp, a.T @ a
+
+
 def youla_transform_np(B: np.ndarray, D: np.ndarray
                        ) -> Tuple[np.ndarray, np.ndarray]:
     """(sigma, T): the Youla change of basis as a K x K *right transform*,

@@ -5,6 +5,9 @@ rejection hot path: it must stay expression-for-expression identical to
 ``core.tree._descend_batch``'s unsharded branch, because the golden-file
 suite pins the sampler's draws bit-for-bit.  Changing an op order here is
 a distribution change and must go through ``--regen-golden`` review.
+
+``descend_pair_ref`` is the Pallas kernel's own rule (both children
+scored at every level, no mass carried), for the kernel tests only.
 """
 import jax
 import jax.numpy as jnp
@@ -58,3 +61,18 @@ def descend_ref(levels, q: jax.Array, us: jax.Array) -> jax.Array:
         p_all = jnp.maximum(jnp.where(go_left, p_left, p_all - p_left), 0.0)
     return idx
 
+
+def descend_pair_ref(levels, q: jax.Array, us: jax.Array) -> jax.Array:
+    """The descent kernel's rule, for tests: at every level both children
+    of each lane's node are scored and the lane goes left iff
+    ``u * max(p_left + p_right, 1e-30) <= max(p_left, 0)``.  Same
+    arguments and result as ``descend_ref``; the root is not read."""
+    idx = jnp.zeros((q.shape[0],), jnp.int32)
+    for lvl in range(1, len(levels)):
+        nodes = levels[lvl]
+        p_left = jnp.einsum("nij,nij->n", q, nodes[2 * idx])
+        p_right = jnp.einsum("nij,nij->n", q, nodes[2 * idx + 1])
+        go_left = us[:, lvl - 1] * jnp.maximum(p_left + p_right, 1e-30) \
+            <= jnp.maximum(p_left, 0.0)
+        idx = 2 * idx + jnp.where(go_left, 0, 1)
+    return idx

@@ -88,7 +88,8 @@ ENGINE_INIT = "engine_init"      # engine_init/<backend>
 FIRST_TICK = "first_tick"        # first_tick/<backend>
 
 SETUP_STAGES = {
-    YOULA: "host Youla decomposition (core.youla.spectral_from_params)",
+    YOULA: "host Youla decomposition and float64 proposal Gram "
+           "(core.youla.spectral_and_gram)",
     PROPOSAL_EIGENS: "proposal eigendecomposition (core.tree.proposal_eigens)",
     TREE_BUILD: "proposal tree build (core.tree.construct_tree)",
     ENGINE_INIT: "SamplerEngine.__init__, per backend (placement, "

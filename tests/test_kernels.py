@@ -11,7 +11,7 @@ from repro.kernels.bilinear.ref import bilinear_batched_ref, bilinear_ref
 from repro.kernels.mcmc_score import ops as mops
 from repro.kernels.mcmc_score.ref import score_all_ref
 from repro.kernels.spec_round import ops as spops
-from repro.kernels.spec_round.ref import descend_ref
+from repro.kernels.spec_round.ref import descend_pair_ref, descend_ref
 from repro.kernels.ssd import ops as sops
 from repro.kernels.ssd.ref import ssd_ref
 from repro.kernels.tree_sum import ops as tops
@@ -100,13 +100,20 @@ def _random_tree_levels(rng, depth, r):
 
 
 @pytest.mark.parametrize("depth,block,r,n", [(3, 4, 8, 5), (5, 8, 16, 12),
-                                             (6, 2, 40, 3), (2, 8, 130, 4)])
+                                             (6, 2, 40, 3), (2, 8, 130, 4),
+                                             (4, 4, 8, 70), (3, 2, 16, 200),
+                                             (1, 4, 8, 5), (1, 2, 130, 20),
+                                             (6, 2, 100, 200)])
 def test_spec_round_descend_score(depth, block, r, n):
-    """HBM-resident descent kernel (interpret mode) vs the jnp oracle:
-    identical block choices, then matching raw leaf scores of the chosen
-    blocks.  Spans shallow-only trees (depth <= 5 under _SHALLOW_MAX=32),
-    deep per-lane gathers, lane counts off the kernel's lane multiple, and
-    R past one 1024-float node slab."""
+    """HBM-resident descent kernel (interpret mode) vs the jnp oracles:
+    block choices identical to the kernel's own rule
+    (``descend_pair_ref``) and to the carried oracle ``descend_ref``, then
+    matching raw leaf scores of the chosen blocks.  Spans shallow-only
+    trees (depth <= 5 under _SHALLOW_MAX=32), deep per-lane gathers,
+    depth 1, lane counts off the kernel's group and step multiples,
+    several lane groups taking turns within a grid step (70, 200),
+    several grid steps at the benchmark's R = 100, and R past one
+    1024-float node slab."""
     rng = np.random.default_rng(depth * 1000 + block * 100 + r)
     levels = _random_tree_levels(rng, depth, r)
     m = (1 << depth) * block
@@ -115,16 +122,105 @@ def test_spec_round_descend_score(depth, block, r, n):
     q = jnp.asarray(np.einsum("nik,njk->nij", qh, qh) / r)
     us = jnp.asarray(rng.uniform(size=(n, depth)), jnp.float32)
     flat = spops.descent_operands(levels, force_interpret=True)
-    assert flat is not None and flat[-1].shape[1:] == (
-        -(-r * r // 1024) * 8, 128)
+    assert flat is not None and len(flat) == depth
+    assert flat[-1].shape[1:] == (-(-r * r // 1024) * 8, 128)
     blk = spops.descend(levels, flat, q, us, force_interpret=True)
     blk_ref = descend_ref(levels, q, us)
+    np.testing.assert_array_equal(np.asarray(blk),
+                                  np.asarray(descend_pair_ref(levels, q, us)))
     np.testing.assert_array_equal(np.asarray(blk), np.asarray(blk_ref))
     rows = blk_ref[:, None] * block + jnp.arange(block)[None, :]
     sc = bops.bilinear_batched(w[rows], q, force_interpret=True)
     np.testing.assert_allclose(np.asarray(sc),
                                np.asarray(bilinear_batched_ref(w[rows], q)),
                                rtol=1e-4, atol=1e-4 * max(1, r))
+
+
+@pytest.mark.parametrize("r", [8, 100, 130, 256])
+@pytest.mark.parametrize("n", [1, 3, 12, 70, 200, 512, 2000])
+def test_descent_lanes_rule(r, n):
+    """The descent kernel's lane groups come from the shapes: whole groups
+    of a multiple of 8 lanes, the lanes per grid step a whole number of
+    groups dividing the padded batch, padding under one step, every
+    buffer within the VMEM budget, and two groups taking turns wherever
+    the batch has two."""
+    s = spops.flat_nodes(jnp.zeros((1, r, r))).shape[1]
+    lanes, group = spops.descent_lanes(n, s)
+    n_pad = n + (-n) % lanes
+    assert group % 8 == 0 and lanes % group == 0
+    assert n_pad % lanes == 0 and n_pad - n < lanes
+    assert spops.vmem_bytes(lanes, s) <= spops.DESCENT_VMEM_BYTES
+    if n > 8:
+        assert lanes >= 2 * group
+    if (r, n) == (100, 512):           # the benchmark cell: no padding
+        assert n_pad == n and lanes < n
+
+
+def test_spec_round_descent_deep_low_rank_matches_float64():
+    """Late in a draw the projector is low rank and orthogonal to where
+    the nodes hold most of their weight, so <Q, node> cancels: here rank 2
+    against rows whose variance is 10^4 times larger along one direction
+    the projector removes, over a depth-12 tree.  The kernel, scoring both
+    children of every node, makes every decision a float64 descent makes.
+    The carried oracle (``descend_ref``: the parent's mass carried down as
+    p_all - p_left) keeps the rounding of the root's score in the mass, so
+    on the float64 path its ratios stray by about 2^level times the pair
+    rule's, and it chooses other blocks.  Uniforms lie in [0.6, 1), so
+    the paths turn right, where the carried mass is subtracted."""
+    depth, block, r, n = 12, 4, 8, 64
+    rng = np.random.default_rng(12)
+    f = rng.normal(size=r)
+    f /= np.linalg.norm(f)
+    w = (100.0 * rng.normal(size=((1 << depth) * block, 1)) * f
+         + rng.normal(size=((1 << depth) * block, r))).astype(np.float32)
+    wb = w.reshape(-1, block, r)
+    nodes = np.einsum("nbi,nbj->nij", wb, wb).astype(np.float32)
+    lv32, lv64 = [nodes], [nodes.astype(np.float64)]
+    for _ in range(depth):
+        lv32.append(lv32[-1][0::2] + lv32[-1][1::2])
+        lv64.append(lv64[-1][0::2] + lv64[-1][1::2])
+    lv32 = tuple(jnp.asarray(x) for x in reversed(lv32))
+    lv64 = lv64[::-1]
+    proj = []
+    for _ in range(n):
+        x = rng.normal(size=(r, 2))
+        x -= np.outer(f, f @ x)
+        basis = np.linalg.qr(x)[0]
+        proj.append(basis @ basis.T)
+    q = jnp.asarray(np.stack(proj), jnp.float32)
+    q64 = np.asarray(q, np.float64)
+    us = rng.uniform(0.6, 1.0, size=(n, depth)).astype(np.float32)
+
+    idx = np.zeros(n, np.int64)            # float64 descent: p_left / p_parent
+    path, ratio64 = [idx], []
+    for lvl in range(1, depth + 1):
+        ratio64.append(np.einsum("nij,nij->n", q64, lv64[lvl][2 * idx])
+                       / np.einsum("nij,nij->n", q64, lv64[lvl - 1][idx]))
+        idx = 2 * idx + (us[:, lvl - 1] > ratio64[-1])
+        path.append(idx)
+    ratio64 = np.stack(ratio64, 1)
+
+    flat = spops.descent_operands(lv32, force_interpret=True)
+    blk = spops.descend(lv32, flat, q, jnp.asarray(us), force_interpret=True)
+    np.testing.assert_array_equal(np.asarray(blk), idx)
+
+    # float32 ratios of both rules on the float64 path
+    p_all = jnp.einsum("ij,nij->n", lv32[0][0], q)
+    carried, pair = [], []
+    for lvl in range(1, depth + 1):
+        left = jnp.asarray(2 * path[lvl - 1])
+        p_left = jnp.einsum("nij,nij->n", q, lv32[lvl][left])
+        p_right = jnp.einsum("nij,nij->n", q, lv32[lvl][left + 1])
+        carried.append(p_left / p_all)
+        pair.append(p_left / (p_left + p_right))
+        p_all = jnp.maximum(jnp.where(jnp.asarray(path[lvl]) == left,
+                                      p_left, p_all - p_left), 0.0)
+    stray_carried = np.abs(np.stack(carried, 1) - ratio64).max(axis=0)
+    stray_pair = np.abs(np.stack(pair, 1) - ratio64).max(axis=0)
+    assert stray_pair.max() < 1e-3          # 9.8e-5 on this input
+    assert stray_carried[-1] > 0.1          # 0.44 on this input
+    assert (stray_carried[-4:] > 100 * stray_pair[-4:]).all()
+    assert (np.asarray(descend_ref(lv32, q, jnp.asarray(us))) != idx).any()
 
 
 def test_spec_round_shallow_max_matches_tree():

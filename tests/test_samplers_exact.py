@@ -124,3 +124,32 @@ def test_tree_vs_dense_proposal(params, rng):
         return out / len(arr)
 
     assert np.abs(incl(t_items) - incl(d_items)).max() < 0.05
+
+
+def test_preprocess_eigenbasis_in_near_degenerate_pairs():
+    """Lhat's eigenvalues come in Youla pairs that V V^T splits only
+    slightly; here V is small, so pairs sit a few float32 ulps apart.
+    ``preprocess`` takes the eigendecomposition from a float64 Gram, so
+    each eigenvector column matches Lhat's own, from a float64 dense
+    eigendecomposition, up to sign and float32 rounding."""
+    rng = np.random.default_rng(15)
+    m, k = 256, 8
+    v = (rng.normal(size=(m, k)) * 1e-3).astype(np.float32)
+    b = rng.normal(size=(m, k)).astype(np.float32)
+    d = rng.normal(size=(k, k)).astype(np.float32)
+    sampler = preprocess(jnp.asarray(v), jnp.asarray(b), jnp.asarray(d),
+                         block=8)
+    w = np.asarray(sampler.tree.W, np.float64)[:m]
+    lam = np.asarray(sampler.tree.lam, np.float64)
+
+    v64, b64 = v.astype(np.float64), b.astype(np.float64)
+    qb, rb = np.linalg.qr(b64)
+    c = rb @ (d - d.T).astype(np.float64) @ rb.T
+    ev, evec = np.linalg.eigh(c.T @ c)
+    abs_c = (evec * np.sqrt(np.maximum(ev, 0.0))) @ evec.T      # |C|
+    lam_exact, w_exact = np.linalg.eigh(v64 @ v64.T + qb @ abs_c @ qb.T)
+    lam_exact, w_exact = lam_exact[-2 * k:], w_exact[:, -2 * k:]
+    assert np.diff(lam_exact).min() < 1e-5 * lam_exact.max()   # near pairs
+    np.testing.assert_allclose(lam, lam_exact, rtol=1e-5, atol=1e-6)
+    cos = np.abs(np.sum(w * w_exact, axis=0))
+    assert cos.min() > 1 - 1e-5, cos

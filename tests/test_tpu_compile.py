@@ -96,15 +96,19 @@ def _kernels(compiled) -> int:
 
 @pytest.mark.parametrize("width", WIDTHS)
 def test_descend_compiles(one_chip, width):
-    """The HBM-resident tree descent: levels stay in HBM, one node per
-    lane per level is DMA'd, so a 2^14-block tree compiles."""
+    """The HBM-resident tree descent: levels below the first stay in HBM,
+    both children of each lane's node are DMA'd per level as one pair, so
+    a 2^14-block tree compiles, with the lane groups ``descent_lanes``
+    picks for the width's slab size."""
     w = WIDTHS[width]
     s = spec_round_ops.flat_nodes(jnp.zeros((1, w.r, w.r))).shape[1]
+    lanes, group = spec_round_ops.descent_lanes(w.lanes, s)
+    n = -(-w.lanes // lanes) * lanes
     levels = tuple(_shape(one_chip, (1 << lvl, s, 128))
-                   for lvl in range(w.depth + 1))
-    c = jax.jit(descend_pallas, static_argnames=("lanes",)).lower(
-        levels, _shape(one_chip, (w.lanes, s, 128)),
-        _shape(one_chip, (w.lanes, w.depth)), lanes=spec_round_ops.LANES,
+                   for lvl in range(1, w.depth + 1))
+    c = descend_pallas.lower(
+        levels, _shape(one_chip, (n, s, 128)),
+        _shape(one_chip, (n, w.depth)), lanes=lanes, group=group,
     ).compile()
     assert _kernels(c) == 1
 
