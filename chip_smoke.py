@@ -405,6 +405,8 @@ def main(argv=None) -> int:
                  f"mean trials {mean_trials} outside {band} x {expect}")
         _require("ndpp_tree_descent" in kernels["rej_tick"],
                  f"descent kernel missing from the rejection tick: {kernels}")
+        _require("ndpp_slogdet" in kernels["rej_tick"],
+                 f"slogdet kernel missing from the rejection tick: {kernels}")
         _require("ndpp_score_all" in kernels["mcmc_init"],
                  f"score_all missing from the MCMC init: {kernels}")
         rec["exactness"] = exactness()

@@ -48,7 +48,7 @@ from .rejection import (
     _fanout_traced,
     _log_det_ratio_rows,
     drive_rounds,
-    log_det_ratio,
+    log_det_ratio_batch,
 )
 from .tree import (
     SampleTree,
@@ -168,10 +168,9 @@ def _spec_round_dual_impl(prop: DualProposal, live_sp: SpectralNDPP,
         items, mask = sample_proposal_dpp_batch(prop.tree, ks[:, 0],
                                                 dual_u=prop.u)
     with jax.named_scope("ndpp.logdet_ratio"):
-        live_x = live_sp.x_matrix()
-        log_ratio, _ = jax.vmap(
-            lambda i, m: log_det_ratio(prop.sp, i, m, live_z=live_sp.Z,
-                                       live_x=live_x))(items, mask)
+        log_ratio, _ = log_det_ratio_batch(prop.sp, items, mask,
+                                           live_z=live_sp.Z,
+                                           live_x=live_sp.x_matrix())
     with jax.named_scope("ndpp.accept"):
         u = jax.vmap(
             lambda k: jax.random.uniform(k, dtype=jnp.float32))(ks[:, 1])
@@ -217,11 +216,9 @@ def _spec_round_dual_sharded_impl(prop: DualProposal, live_sp: SpectralNDPP,
             zy = msh.gather_rows(p_loc.sp.Z, items, mask, axis_name=z_axis)
             zy_live = msh.gather_rows(live_loc.Z, items, mask,
                                       axis_name=z_axis)
-            live_x = live_loc.x_matrix()
-            log_ratio, _ = jax.vmap(
-                lambda a, b, m_: _log_det_ratio_rows(
-                    p_loc.sp, a, m_, live_rows=b, live_x=live_x)
-            )(zy, zy_live, mask)
+            log_ratio, _ = _log_det_ratio_rows(
+                p_loc.sp, zy, mask, live_rows=zy_live,
+                live_x=live_loc.x_matrix())
         with jax.named_scope("ndpp.accept"):
             u = jax.vmap(
                 lambda k: jax.random.uniform(k, dtype=jnp.float32))(ks[:, 1])

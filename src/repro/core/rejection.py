@@ -21,6 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.kernels.slogdet.ops import slogdet_batched
+
 from .types import SpectralNDPP
 from .tree import (
     SampleTree,
@@ -95,7 +97,7 @@ def preprocess(V: jax.Array, B: jax.Array, D: jax.Array, block: int = 64) -> NDP
 
 def _masked_rows(Z: jax.Array, items: jax.Array, mask: jax.Array) -> jax.Array:
     rows = Z[jnp.maximum(items, 0)]
-    return rows * mask[:, None].astype(Z.dtype)
+    return rows * mask[..., None].astype(Z.dtype)
 
 
 def log_det_ratio(
@@ -118,10 +120,9 @@ def log_det_ratio(
     makes sign(det L_Y) = 0 here, so deleted items are rejected with
     probability one.
     """
-    zy = _masked_rows(sp.Z, items, mask)
-    live_rows = None if live_z is None else _masked_rows(live_z, items, mask)
-    return _log_det_ratio_rows(sp, zy, mask, live_rows=live_rows,
-                               live_x=live_x)
+    ratio, sign = log_det_ratio_batch(sp, items[None], mask[None],
+                                      live_z=live_z, live_x=live_x)
+    return ratio[0], sign[0]
 
 
 def _log_det_ratio_rows(
@@ -129,19 +130,29 @@ def _log_det_ratio_rows(
     live_rows: Optional[jax.Array] = None,
     live_x: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """``log_det_ratio`` from pre-gathered (k_pad, 2K) subset rows ``zy``
-    (padding rows already zeroed) — the sharded round gathers rows across
-    shards first and shares this 2K-space math.  ``live_rows``/``live_x``:
-    pre-gathered numerator overrides (see ``log_det_ratio``)."""
+    """``log_det_ratio`` of N subsets from pre-gathered (N, k_pad, 2K)
+    subset rows ``zy`` (padding rows already zeroed) — the sharded round
+    gathers rows across shards first and shares this 2K-space math.
+    ``live_rows``/``live_x``: pre-gathered numerator overrides (see
+    ``log_det_ratio``).  The 2N submatrices are factored by one
+    ``slogdet_batched`` call: on a TPU one lane per matrix, to the largest
+    live prefix of each 128 (the mask is a prefix of each padded subset)."""
     x = sp.x_matrix() if live_x is None else live_x
     num = zy if live_rows is None else live_rows
-    pad_eye = jnp.diag((~mask).astype(zy.dtype))
-    l_y = num @ x @ num.T + pad_eye
-    lhat_y = (zy * sp.x_diag_hat()[None, :]) @ zy.T + pad_eye
-    sign_l, logdet_l = jnp.linalg.slogdet(l_y)
-    sign_h, logdet_h = jnp.linalg.slogdet(lhat_y)
+    k = mask.shape[-1]
+    pad_eye = (~mask)[:, :, None] * jnp.eye(k, dtype=zy.dtype)
+    l_y = num @ x @ jnp.swapaxes(num, -1, -2) + pad_eye
+    lhat_y = ((zy * sp.x_diag_hat()) @ jnp.swapaxes(zy, -1, -2)
+              + pad_eye)
+    # rows past the last live one are identity rows
+    n_live = jnp.max(jnp.where(mask, jnp.arange(1, k + 1, dtype=jnp.int32),
+                               0), axis=-1)
+    sign, logdet = slogdet_batched(jnp.concatenate([l_y, lhat_y]),
+                                   jnp.concatenate([n_live, n_live]))
+    n = mask.shape[0]
+    sign_l, sign_h = sign[:n], sign[n:]
     good = (sign_l > 0) & (sign_h > 0)
-    return jnp.where(good, logdet_l - logdet_h, -jnp.inf), sign_l
+    return jnp.where(good, logdet[:n] - logdet[n:], -jnp.inf), sign_l
 
 
 def expected_trials(sp: SpectralNDPP) -> jax.Array:
@@ -217,16 +228,19 @@ def sample_batch(
 
 
 def log_det_ratio_batch(
-    sp: SpectralNDPP, items: jax.Array, mask: jax.Array
+    sp: SpectralNDPP, items: jax.Array, mask: jax.Array,
+    live_z: Optional[jax.Array] = None, live_x: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """``log_det_ratio`` over N padded subsets at once.
 
     items/mask: (N, k_pad).  Returns ((N,) log ratios, (N,) signs): both
-    k_pad x k_pad submatrices are built batched and factored with one
-    batched slogdet instead of N separate ones (vmap lifts the einsums and
-    slogdet of ``log_det_ratio`` to their batched forms).
+    k_pad x k_pad submatrices of every subset are built batched and
+    factored by one batched slogdet (``_log_det_ratio_rows``).
     """
-    return jax.vmap(lambda i, m: log_det_ratio(sp, i, m))(items, mask)
+    zy = _masked_rows(sp.Z, items, mask)
+    live_rows = None if live_z is None else _masked_rows(live_z, items, mask)
+    return _log_det_ratio_rows(sp, zy, mask, live_rows=live_rows,
+                               live_x=live_x)
 
 
 def _spec_round_impl(sampler: NDPPSampler, keys: jax.Array):
@@ -290,8 +304,7 @@ def _spec_round_sharded_impl(sampler: NDPPSampler, keys: jax.Array,
                 s_loc.tree, ks[:, 0], axis_name="model", m_pad_global=m_pad)
         with jax.named_scope("ndpp.logdet_ratio"):
             zy = msh.gather_rows(s_loc.sp.Z, items, mask, axis_name=z_axis)
-            log_ratio, _ = jax.vmap(
-                lambda r_, m_: _log_det_ratio_rows(s_loc.sp, r_, m_))(zy, mask)
+            log_ratio, _ = _log_det_ratio_rows(s_loc.sp, zy, mask)
         with jax.named_scope("ndpp.accept"):
             u = jax.vmap(
                 lambda k: jax.random.uniform(k, dtype=jnp.float32))(ks[:, 1])

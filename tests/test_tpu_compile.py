@@ -31,13 +31,19 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import mcmc as mcmc_core
-from repro.core.rejection import NDPPSampler, _spec_round_fused_sharded
+from repro.core.rejection import (
+    NDPPSampler,
+    _spec_round_fused,
+    _spec_round_fused_sharded,
+)
 from repro.core.tree import construct_tree, proposal_eigens, tree_shard_specs
 from repro.core.types import SpectralNDPP
 from repro.kernels.bilinear import ops as bilinear_ops
 from repro.kernels.bilinear.bilinear import bilinear_batched_pallas
 from repro.kernels.mcmc_score import ops as mcmc_score_ops
 from repro.kernels.mcmc_score.mcmc_score import score_all_pallas
+from repro.kernels.slogdet import ops as slogdet_ops
+from repro.kernels.slogdet.slogdet import slogdet_pallas
 from repro.kernels.spec_round import ops as spec_round_ops
 from repro.kernels.spec_round.spec_round import descend_pallas
 from repro.kernels.tree_sum.tree_sum import (
@@ -155,22 +161,58 @@ def test_gathered_block_grams_compiles(one_chip, width):
     assert _kernels(c) == 1
 
 
-def test_sharded_paths_compile(v5e_devices, monkeypatch):
-    """The item-sharded engines' programs over a 4-chip mesh at the catalog
-    width: the rejection tick and the MCMC greedy init.  XLA cannot
-    partition a Pallas kernel, so each one must sit inside a shard_map."""
-    for ops in (bilinear_ops, mcmc_score_ops):
-        monkeypatch.setattr(ops, "on_tpu", lambda: True)
-    w = WIDTHS["catalog"]
-    mesh = Mesh(np.asarray(v5e_devices[:4]), ("model",))
+@pytest.mark.parametrize("width", WIDTHS)
+def test_slogdet_compiles(one_chip, width):
+    """The acceptance test's log-dets: L_Y and Lhat_Y of every lane, one
+    matrix per vector lane, k = R rows on the leading dim and R columns
+    on sublanes; the block's working copy must fit the VMEM limit."""
+    w = WIDTHS[width]
+    n = -(-2 * w.lanes // slogdet_ops.LANES) * slogdet_ops.LANES
+    t = -(-w.r // 8)
+    c = slogdet_pallas.lower(
+        _shape(one_chip, (w.r, t, 8, n)),
+        _shape(one_chip, (n // slogdet_ops.LANES,), jnp.int32),
+    ).compile()
+    assert _kernels(c) == 1
 
+
+def _sampler_shapes(w: Width):
     def build(z, sigma):
         sp = SpectralNDPP(Z=z, sigma=sigma)
         return NDPPSampler(sp=sp, tree=construct_tree(*proposal_eigens(sp),
                                                       block=w.block))
 
-    shapes = jax.eval_shape(build, jax.ShapeDtypeStruct((w.m, w.r), jnp.float32),
-                            jax.ShapeDtypeStruct((w.r // 4,), jnp.float32))
+    return jax.eval_shape(
+        build, jax.ShapeDtypeStruct((w.m, w.r), jnp.float32),
+        jax.ShapeDtypeStruct((w.r // 4,), jnp.float32))
+
+
+def test_spec_round_fused_has_no_lu(one_chip, monkeypatch):
+    """On a TPU the rejection tick factors its log-dets in the slogdet
+    kernel, not in XLA's per-matrix ``LuDecomposition`` custom call."""
+    for ops in (bilinear_ops, spec_round_ops, slogdet_ops):
+        monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    w = WIDTHS["catalog"]
+    sampler = jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype),
+                           _sampler_shapes(w))
+    slots = 8
+    text = _spec_round_fused.lower(
+        sampler, _shape(one_chip, (slots, 2), jnp.uint32),
+        _shape(one_chip, (slots,), jnp.uint32), n_spec=w.lanes // slots,
+    ).as_text()
+    assert "ndpp_slogdet" in text
+    assert "LuDecomposition" not in text
+
+
+def test_sharded_paths_compile(v5e_devices, monkeypatch):
+    """The item-sharded engines' programs over a 4-chip mesh at the catalog
+    width: the rejection tick and the MCMC greedy init.  XLA cannot
+    partition a Pallas kernel, so each one must sit inside a shard_map."""
+    for ops in (bilinear_ops, mcmc_score_ops, slogdet_ops):
+        monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    w = WIDTHS["catalog"]
+    mesh = Mesh(np.asarray(v5e_devices[:4]), ("model",))
+    shapes = _sampler_shapes(w)
     specs = NDPPSampler(sp=SpectralNDPP(Z=P("model", None), sigma=P(None)),
                         tree=tree_shard_specs(shapes.tree, mesh))
     sampler = jax.tree.map(
