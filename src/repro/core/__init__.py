@@ -46,7 +46,6 @@ from .tree import (  # noqa: F401
     sample_proposal_dpp_batch_sharded,
     sample_elementary,
     sample_elementary_batch,
-    sample_elementary_batch_sharded,
     sample_elementary_dense,
     shard_spectral,
     shard_tree,

@@ -41,22 +41,14 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh
 
-from .rejection import (
-    RejectionSample,
-    _fanout_traced,
-    _log_det_ratio_rows,
-    drive_rounds,
-    log_det_ratio_batch,
-)
+from .rejection import RejectionSample, drive_rounds
 from .tree import (
     SampleTree,
     construct_tree,
-    sample_proposal_dpp_batch,
     shard_spectral,
     shard_tree,
-    tree_shard_specs,
     update_rows,
     update_rows_sharded,
 )
@@ -71,7 +63,8 @@ class DualProposal:
       tree: flat sample tree over the dual rows A (``tree.W`` holds A,
         ``tree.lam`` the eigenvalues of C = Aᵀ A — equal to L̂'s nonzero
         spectrum).
-      u: (R, R) eigenvectors of C (builds the ``dual_q0`` projectors).
+      dual_u: (R, R) eigenvectors of C (builds the ``dual_q0``
+        projectors).
       sp: the spectral state A was derived from — the acceptance
         denominator det(L̂_Y) is scored against *these* rows, because this
         is the kernel the tree actually proposes from, even when the live
@@ -82,7 +75,7 @@ class DualProposal:
     """
 
     tree: SampleTree
-    u: jax.Array
+    dual_u: jax.Array
     sp: SpectralNDPP
 
     @property
@@ -92,8 +85,8 @@ class DualProposal:
 
 jax.tree_util.register_pytree_node(
     DualProposal,
-    lambda p: ((p.tree, p.u, p.sp), None),
-    lambda _, c: DualProposal(tree=c[0], u=c[1], sp=c[2]),
+    lambda p: ((p.tree, p.dual_u, p.sp), None),
+    lambda _, c: DualProposal(tree=c[0], dual_u=c[1], sp=c[2]),
 )
 
 
@@ -124,7 +117,7 @@ def build_dual_proposal(sp: SpectralNDPP, block: int = 64,
     if mesh is not None:
         tree = shard_tree(tree, mesh)
         sp = shard_spectral(sp, mesh)
-    return DualProposal(tree=tree, u=u, sp=sp)
+    return DualProposal(tree=tree, dual_u=u, sp=sp)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh",))
@@ -150,120 +143,8 @@ def update_proposal(prop: DualProposal, idx: jax.Array, z_rows: jax.Array,
     else:
         tree = update_rows_sharded(prop.tree, idx, a_rows, mesh)
     lam, u = dual_eigens(tree.levels[0][0])
-    return DualProposal(tree=dataclasses.replace(tree, lam=lam), u=u,
+    return DualProposal(tree=dataclasses.replace(tree, lam=lam), dual_u=u,
                         sp=new_sp)
-
-
-# ------------------------------------------------------------ sampling rounds
-
-
-def _spec_round_dual_impl(prop: DualProposal, live_sp: SpectralNDPP,
-                          keys: jax.Array):
-    """Traced body of one dual-proposal round (shared by the standalone
-    dispatch and the fused variant that folds the key fan-out in)."""
-    # scope names from the repro.obs.prof.phases catalog (free HLO
-    # metadata; traced bodies never touch repro.obs)
-    ks = jax.vmap(jax.random.split)(keys)
-    with jax.named_scope("ndpp.proposal"):
-        items, mask = sample_proposal_dpp_batch(prop.tree, ks[:, 0],
-                                                dual_u=prop.u)
-    with jax.named_scope("ndpp.logdet_ratio"):
-        log_ratio, _ = log_det_ratio_batch(prop.sp, items, mask,
-                                           live_z=live_sp.Z,
-                                           live_x=live_sp.x_matrix())
-    with jax.named_scope("ndpp.accept"):
-        u = jax.vmap(
-            lambda k: jax.random.uniform(k, dtype=jnp.float32))(ks[:, 1])
-        accept = jnp.log(u) <= log_ratio
-    return items, mask, accept
-
-
-@jax.jit
-def _spec_round_dual(prop: DualProposal, live_sp: SpectralNDPP,
-                     keys: jax.Array):
-    """One speculative round against a (possibly stale) dual proposal: the
-    tree proposes from L̂_snap, the acceptance test rescores the *live*
-    kernel.  Key schedule identical to ``rejection._spec_round``, so a
-    request's draw is independent of which proposal version served it —
-    as long as that version's arrays are the ones passed here (the
-    engine's version pinning)."""
-    return _spec_round_dual_impl(prop, live_sp, keys)
-
-
-def _spec_round_dual_sharded_impl(prop: DualProposal, live_sp: SpectralNDPP,
-                                  keys: jax.Array, mesh: Mesh):
-    """Traced body of ``_spec_round_dual_sharded`` (shared with the fused
-    sharded variant)."""
-    from repro.models import sharding as msh
-
-    s = msh.model_extent(mesh)
-    z_spec = msh.logical_to_spec(mesh, ("items", None), prop.sp.Z.shape)
-    z_axis = "model" if (s > 1 and z_spec != P(None, None)
-                         and z_spec[0] is not None) else None
-    prop_specs = DualProposal(
-        tree=tree_shard_specs(prop.tree, mesh), u=P(None, None),
-        sp=SpectralNDPP(Z=z_spec, sigma=P(None)))
-    live_specs = SpectralNDPP(Z=z_spec, sigma=P(None))
-    m_pad = prop.tree.W.shape[0]
-
-    def inner(p_loc, live_loc, keys):
-        ks = jax.vmap(jax.random.split)(keys)
-        with jax.named_scope("ndpp.proposal"):
-            items, mask = sample_proposal_dpp_batch(
-                p_loc.tree, ks[:, 0], axis_name="model", m_pad_global=m_pad,
-                dual_u=p_loc.u)
-        with jax.named_scope("ndpp.logdet_ratio"):
-            zy = msh.gather_rows(p_loc.sp.Z, items, mask, axis_name=z_axis)
-            zy_live = msh.gather_rows(live_loc.Z, items, mask,
-                                      axis_name=z_axis)
-            log_ratio, _ = _log_det_ratio_rows(
-                p_loc.sp, zy, mask, live_rows=zy_live,
-                live_x=live_loc.x_matrix())
-        with jax.named_scope("ndpp.accept"):
-            u = jax.vmap(
-                lambda k: jax.random.uniform(k, dtype=jnp.float32))(ks[:, 1])
-            accept = jnp.log(u) <= log_ratio
-        return items, mask, accept
-
-    f = jax.shard_map(inner, mesh=mesh,
-                      in_specs=(prop_specs, live_specs, P(None)),
-                      out_specs=(P(None),) * 3, check_vma=False)
-    return f(prop, live_sp, keys)
-
-
-@functools.partial(jax.jit, static_argnames=("mesh",))
-def _spec_round_dual_sharded(prop: DualProposal, live_sp: SpectralNDPP,
-                             keys: jax.Array, mesh: Mesh):
-    """``_spec_round_dual`` over a device mesh: tree descent, leaf scoring,
-    and the snapshot/live Z-row gathers all run on the owning shard and
-    combine by psums of exact zeros (the PR-3 invariant) — bit-identical
-    to the unsharded round."""
-    return _spec_round_dual_sharded_impl(prop, live_sp, keys, mesh)
-
-
-@functools.partial(jax.jit, static_argnames=("n_spec",))
-def _spec_round_dual_fused(prop: DualProposal, live_sp: SpectralNDPP,
-                           slot_keys: jax.Array, trials: jax.Array, *,
-                           n_spec: int):
-    """One dual-proposal round with the key fan-out folded into the same
-    jit — the engine's single dispatch per tick for slots pinned to a
-    stale proposal snapshot.  Key schedule (``fold_in(slot_keys[i],
-    trials[i] + t)``) and every downstream op match the two-dispatch
-    ``_fanout_keys`` + ``_spec_round_dual`` path bit for bit."""
-    offsets = jnp.arange(n_spec, dtype=jnp.uint32)
-    keys = _fanout_traced(slot_keys, trials, offsets)
-    return _spec_round_dual_impl(prop, live_sp, keys)
-
-
-@functools.partial(jax.jit, static_argnames=("mesh", "n_spec"))
-def _spec_round_dual_fused_sharded(prop: DualProposal, live_sp: SpectralNDPP,
-                                   slot_keys: jax.Array, trials: jax.Array,
-                                   mesh: Mesh, *, n_spec: int):
-    """``_spec_round_dual_fused`` over a device mesh (fan-out traced on
-    the replicated keys, then the one shard_map round)."""
-    offsets = jnp.arange(n_spec, dtype=jnp.uint32)
-    keys = _fanout_traced(slot_keys, trials, offsets)
-    return _spec_round_dual_sharded_impl(prop, live_sp, keys, mesh)
 
 
 # ------------------------------------------------------------------- drivers
@@ -323,10 +204,6 @@ def sample_dynamic_many(
         req_keys = jax.random.split(key, n)
     else:
         req_keys = jnp.asarray(key)
-    round_fn = (
-        (lambda keys: _spec_round_dual(prop, live_sp, keys)) if mesh is None
-        else (lambda keys: _spec_round_dual_sharded(prop, live_sp, keys,
-                                                    mesh)))
-    return drive_rounds(round_fn, req_keys, prop.R, n_spec=n_spec,
-                        max_trials=max_trials, grow=grow, max_spec=max_spec,
-                        observer=observer)
+    return drive_rounds(prop, req_keys, n_spec=n_spec, live=live_sp,
+                        mesh=mesh, max_trials=max_trials, grow=grow,
+                        max_spec=max_spec, observer=observer)

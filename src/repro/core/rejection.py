@@ -60,6 +60,10 @@ class NDPPSampler:
     sp: SpectralNDPP
     tree: SampleTree
 
+    #: the tree's rows are L̂'s eigenvectors, so there is no dual basis
+    #: (``core.dynamic.DualProposal.dual_u``)
+    dual_u = None
+
     @property
     def M(self) -> int:
         return self.sp.M
@@ -95,11 +99,6 @@ def preprocess(V: jax.Array, B: jax.Array, D: jax.Array, block: int = 64) -> NDP
     return NDPPSampler(sp=sp, tree=tree)
 
 
-def _masked_rows(Z: jax.Array, items: jax.Array, mask: jax.Array) -> jax.Array:
-    rows = Z[jnp.maximum(items, 0)]
-    return rows * mask[..., None].astype(Z.dtype)
-
-
 def log_det_ratio(
     sp: SpectralNDPP, items: jax.Array, mask: jax.Array,
     live_z: Optional[jax.Array] = None, live_x: Optional[jax.Array] = None,
@@ -123,36 +122,6 @@ def log_det_ratio(
     ratio, sign = log_det_ratio_batch(sp, items[None], mask[None],
                                       live_z=live_z, live_x=live_x)
     return ratio[0], sign[0]
-
-
-def _log_det_ratio_rows(
-    sp: SpectralNDPP, zy: jax.Array, mask: jax.Array,
-    live_rows: Optional[jax.Array] = None,
-    live_x: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """``log_det_ratio`` of N subsets from pre-gathered (N, k_pad, 2K)
-    subset rows ``zy`` (padding rows already zeroed) — the sharded round
-    gathers rows across shards first and shares this 2K-space math.
-    ``live_rows``/``live_x``: pre-gathered numerator overrides (see
-    ``log_det_ratio``).  The 2N submatrices are factored by one
-    ``slogdet_batched`` call: on a TPU one lane per matrix, to the largest
-    live prefix of each 128 (the mask is a prefix of each padded subset)."""
-    x = sp.x_matrix() if live_x is None else live_x
-    num = zy if live_rows is None else live_rows
-    k = mask.shape[-1]
-    pad_eye = (~mask)[:, :, None] * jnp.eye(k, dtype=zy.dtype)
-    l_y = num @ x @ jnp.swapaxes(num, -1, -2) + pad_eye
-    lhat_y = ((zy * sp.x_diag_hat()) @ jnp.swapaxes(zy, -1, -2)
-              + pad_eye)
-    # rows past the last live one are identity rows
-    n_live = jnp.max(jnp.where(mask, jnp.arange(1, k + 1, dtype=jnp.int32),
-                               0), axis=-1)
-    sign, logdet = slogdet_batched(jnp.concatenate([l_y, lhat_y]),
-                                   jnp.concatenate([n_live, n_live]))
-    n = mask.shape[0]
-    sign_l, sign_h = sign[:n], sign[n:]
-    good = (sign_l > 0) & (sign_h > 0)
-    return jnp.where(good, logdet[:n] - logdet[n:], -jnp.inf), sign_l
 
 
 def expected_trials(sp: SpectralNDPP) -> jax.Array:
@@ -230,33 +199,69 @@ def sample_batch(
 def log_det_ratio_batch(
     sp: SpectralNDPP, items: jax.Array, mask: jax.Array,
     live_z: Optional[jax.Array] = None, live_x: Optional[jax.Array] = None,
+    *, axis_name: Optional[str] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """``log_det_ratio`` over N padded subsets at once.
 
-    items/mask: (N, k_pad).  Returns ((N,) log ratios, (N,) signs): both
-    k_pad x k_pad submatrices of every subset are built batched and
-    factored by one batched slogdet (``_log_det_ratio_rows``).
+    items/mask: (N, k_pad).  Returns ((N,) log ratios, (N,) signs).  The
+    subset rows are gathered with ``gather_rows``: inside a ``shard_map``
+    whose Z rows are sharded over ``axis_name``, each row comes from its
+    owner shard by a psum of exact zeros.  The 2N submatrices L_Y and
+    L̂_Y are factored by one ``slogdet_batched`` call: on a TPU one lane
+    per matrix, to the largest live prefix of each 128 (the mask is a
+    prefix of each padded subset).
     """
-    zy = _masked_rows(sp.Z, items, mask)
-    live_rows = None if live_z is None else _masked_rows(live_z, items, mask)
-    return _log_det_ratio_rows(sp, zy, mask, live_rows=live_rows,
-                               live_x=live_x)
+    from repro.models.sharding import gather_rows
+
+    zy = gather_rows(sp.Z, items, mask, axis_name=axis_name)
+    num = zy if live_z is None else gather_rows(live_z, items, mask,
+                                                axis_name=axis_name)
+    x = sp.x_matrix() if live_x is None else live_x
+    k = mask.shape[-1]
+    pad_eye = (~mask)[:, :, None] * jnp.eye(k, dtype=zy.dtype)
+    l_y = num @ x @ jnp.swapaxes(num, -1, -2) + pad_eye
+    lhat_y = ((zy * sp.x_diag_hat()) @ jnp.swapaxes(zy, -1, -2)
+              + pad_eye)
+    # rows past the last live one are identity rows
+    n_live = jnp.max(jnp.where(mask, jnp.arange(1, k + 1, dtype=jnp.int32),
+                               0), axis=-1)
+    sign, logdet = slogdet_batched(jnp.concatenate([l_y, lhat_y]),
+                                   jnp.concatenate([n_live, n_live]))
+    n = mask.shape[0]
+    sign_l, sign_h = sign[:n], sign[n:]
+    good = (sign_l > 0) & (sign_h > 0)
+    return jnp.where(good, logdet[:n] - logdet[n:], -jnp.inf), sign_l
 
 
-def _spec_round_impl(sampler: NDPPSampler, keys: jax.Array):
+def _round_body(tree: SampleTree, sp: SpectralNDPP,
+                dual_u: Optional[jax.Array], live: Optional[SpectralNDPP],
+                keys: jax.Array, *, axis_name: Optional[str] = None,
+                m_pad: Optional[int] = None, z_axis: Optional[str] = None):
     """Traced body of one speculative round: draw one proposal per key
     (batched tree traversal), score all of them with one batched log-det
     ratio, and flip each acceptance coin.  Returns (items, mask, accept),
-    leading dim N.  Shared by ``_spec_round`` (standalone dispatch),
-    ``_spec_round_fused`` (fan-out folded into the same jit), and the
-    device-resident round loop of ``_drive_rounds_fused``."""
+    leading dim N.
+
+    ``tree``/``sp``/``dual_u`` are the proposal's parts: its tree, the
+    spectral state that scores the denominator det(L̂_Y), and the dual
+    eigenvectors of a ``core.dynamic.DualProposal`` (None for a static
+    sampler, whose tree rows are already L̂'s eigenvectors).  ``live``: the
+    live kernel that scores the numerator (``log_det_ratio``), None for a
+    static catalog.  Inside a ``shard_map``, ``axis_name``/``m_pad`` thread
+    the mesh down to the descent (``sample_elementary_batch``) and
+    ``z_axis`` names the axis the Z rows are sharded over, if any."""
     # scope names from the repro.obs.prof.phases catalog (free HLO
     # metadata; traced bodies never touch repro.obs)
     ks = jax.vmap(jax.random.split)(keys)
     with jax.named_scope("ndpp.proposal"):
-        items, mask = sample_proposal_dpp_batch(sampler.tree, ks[:, 0])
+        items, mask = sample_proposal_dpp_batch(
+            tree, ks[:, 0], axis_name=axis_name, m_pad_global=m_pad,
+            dual_u=dual_u)
     with jax.named_scope("ndpp.logdet_ratio"):
-        log_ratio, _ = log_det_ratio_batch(sampler.sp, items, mask)
+        log_ratio, _ = log_det_ratio_batch(
+            sp, items, mask, live_z=None if live is None else live.Z,
+            live_x=None if live is None else live.x_matrix(),
+            axis_name=z_axis)
     with jax.named_scope("ndpp.accept"):
         u = jax.vmap(
             lambda k: jax.random.uniform(k, dtype=jnp.float32))(ks[:, 1])
@@ -264,74 +269,23 @@ def _spec_round_impl(sampler: NDPPSampler, keys: jax.Array):
     return items, mask, accept
 
 
-@jax.jit
-def _spec_round(sampler: NDPPSampler, keys: jax.Array):
-    """One speculative round as its own dispatch (see ``_spec_round_impl``)."""
-    return _spec_round_impl(sampler, keys)
-
-
 def shard_sampler(sampler: NDPPSampler, mesh: Mesh) -> NDPPSampler:
     """Place a preprocessed sampler on a device mesh: tree deep levels, W,
     and the Z rows are item-sharded over the mesh "model" axis (shallow
     levels, lam, sigma replicated).  The sharded sampler draws bit-identical
-    samples through ``_spec_round_sharded`` / ``sample_batched_many(mesh=)``.
+    samples through ``_spec_round_fused(mesh=)`` /
+    ``sample_batched_many(mesh=)``.
     """
     return NDPPSampler(sp=shard_spectral(sampler.sp, mesh),
                        tree=shard_tree(sampler.tree, mesh))
-
-
-def _spec_round_sharded_impl(sampler: NDPPSampler, keys: jax.Array,
-                             mesh: Mesh):
-    """Traced body of ``_spec_round_sharded`` (shared with the fused
-    sharded round, which folds the key fan-out into the same jit)."""
-    from repro.models import sharding as msh
-
-    s = msh.model_extent(mesh)
-    z_spec = msh.logical_to_spec(mesh, ("items", None), sampler.sp.Z.shape)
-    z_axis = "model" if (s > 1 and z_spec != P(None, None)
-                         and z_spec[0] is not None) else None
-    in_specs = (
-        NDPPSampler(sp=SpectralNDPP(Z=z_spec, sigma=P(None)),
-                    tree=tree_shard_specs(sampler.tree, mesh)),
-        P(None),
-    )
-    m_pad = sampler.tree.W.shape[0]
-
-    def inner(s_loc, keys):
-        ks = jax.vmap(jax.random.split)(keys)
-        with jax.named_scope("ndpp.proposal"):
-            items, mask = sample_proposal_dpp_batch(
-                s_loc.tree, ks[:, 0], axis_name="model", m_pad_global=m_pad)
-        with jax.named_scope("ndpp.logdet_ratio"):
-            zy = msh.gather_rows(s_loc.sp.Z, items, mask, axis_name=z_axis)
-            log_ratio, _ = _log_det_ratio_rows(s_loc.sp, zy, mask)
-        with jax.named_scope("ndpp.accept"):
-            u = jax.vmap(
-                lambda k: jax.random.uniform(k, dtype=jnp.float32))(ks[:, 1])
-            accept = jnp.log(u) <= log_ratio
-        return items, mask, accept
-
-    f = jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
-                      out_specs=(P(None),) * 3, check_vma=False)
-    return f(sampler, keys)
-
-
-@functools.partial(jax.jit, static_argnames=("mesh",))
-def _spec_round_sharded(sampler: NDPPSampler, keys: jax.Array, mesh: Mesh):
-    """``_spec_round`` over a device mesh: one shard_map in which the tree
-    descent, leaf scoring, and the Z-row gathers for the log-det ratio all
-    happen on the shard owning the items, combined by psums of exact zeros.
-    Only the (N, R)-shaped proposal subsets and (N,) scores cross shards —
-    never an (M, ...)-shaped array.  Bit-identical to ``_spec_round``."""
-    return _spec_round_sharded_impl(sampler, keys, mesh)
 
 
 def _fanout_traced(req_keys: jax.Array, starts: jax.Array,
                    offsets: jax.Array) -> jax.Array:
     """Traced key fan-out: key of proposal t for request i is
     fold_in(req_keys[i], starts[i] + t).  Returns (P * S, 2).  fold_in is
-    integer arithmetic, so the keys are bit-identical whether this runs as
-    its own dispatch (``_fanout_keys``) or inside a fused round jit."""
+    integer arithmetic, so a proposal's key depends only on (request key,
+    t), never on the round that draws it."""
 
     def per_req(k, s):
         return jax.vmap(lambda o: jax.random.fold_in(k, s + o))(offsets)
@@ -339,40 +293,45 @@ def _fanout_traced(req_keys: jax.Array, starts: jax.Array,
     return jax.vmap(per_req)(req_keys, starts).reshape(-1, req_keys.shape[-1])
 
 
-@jax.jit
-def _fanout_keys(req_keys: jax.Array, starts: jax.Array, offsets: jax.Array):
-    """Standalone-dispatch form of ``_fanout_traced`` (the pre-fusion hot
-    path; kept for the observer-instrumented Python driver)."""
-    return _fanout_traced(req_keys, starts, offsets)
+@functools.partial(jax.jit, static_argnames=("n_spec", "mesh"))
+def _spec_round_fused(proposal, slot_keys: jax.Array, trials: jax.Array, *,
+                      n_spec: int, live: Optional[SpectralNDPP] = None,
+                      mesh: Optional[Mesh] = None):
+    """One speculative round, key fan-out included, as one jit: the engine
+    tick's single dispatch and the host driver's round.
 
+    ``proposal``: an ``NDPPSampler`` or a ``core.dynamic.DualProposal``
+    (its ``tree``, ``sp`` and ``dual_u``); ``live``: the live kernel the
+    acceptance test scores (None: the proposal's own).  ``slot_keys`` (n, 2)
+    are per-request base keys, ``trials`` (n,) uint32 the per-request
+    proposal counts already spent; proposal t of request i is keyed
+    ``fold_in(slot_keys[i], trials[i] + t)``, t < ``n_spec``.  Returns
+    (items, mask, accept) with leading dim n * n_spec.
 
-@functools.partial(jax.jit, static_argnames=("n_spec",))
-def _spec_round_fused(sampler: NDPPSampler, slot_keys: jax.Array,
-                      trials: jax.Array, *, n_spec: int):
-    """One speculative round with the key fan-out folded into the same jit:
-    the engine tick's single dispatch.
-
-    ``slot_keys`` (n, 2) are per-request base keys, ``trials`` (n,) uint32
-    the per-request proposal counts already spent; proposal t of request i
-    is keyed ``fold_in(slot_keys[i], trials[i] + t)`` exactly as in the
-    two-dispatch ``_fanout_keys`` + ``_spec_round`` path, so draws are
-    bit-identical — the offsets ``arange(n_spec)`` become a traced constant
-    instead of a per-tick h2d transfer.  Returns (items, mask, accept) with
-    leading dim n * n_spec."""
+    ``mesh``: the fan-out runs on the replicated keys, then the same body
+    runs in one shard_map in which the tree descent, leaf scoring and the
+    Z-row gathers for the log-det ratio happen on the shard owning the
+    items, combined by psums of exact zeros.  Only the (N, R)-shaped
+    subsets and (N,) scores cross shards, never an (M, ...)-shaped array,
+    and the result is bit-identical to the one-device round."""
     offsets = jnp.arange(n_spec, dtype=jnp.uint32)
     keys = _fanout_traced(slot_keys, trials, offsets)
-    return _spec_round_impl(sampler, keys)
+    parts = (proposal.tree, proposal.sp, proposal.dual_u, live)
+    if mesh is None:
+        return _round_body(*parts, keys)
+    from repro.models import sharding as msh
 
-
-@functools.partial(jax.jit, static_argnames=("mesh", "n_spec"))
-def _spec_round_fused_sharded(sampler: NDPPSampler, slot_keys: jax.Array,
-                              trials: jax.Array, mesh: Mesh, *, n_spec: int):
-    """``_spec_round_fused`` over a device mesh: fan-out traced on the
-    replicated keys, then the one shard_map round.  Bit-identical to the
-    two-dispatch sharded path."""
-    offsets = jnp.arange(n_spec, dtype=jnp.uint32)
-    keys = _fanout_traced(slot_keys, trials, offsets)
-    return _spec_round_sharded_impl(sampler, keys, mesh)
+    z_spec = msh.logical_to_spec(mesh, ("items", None), proposal.sp.Z.shape)
+    sp_spec = SpectralNDPP(Z=z_spec, sigma=P(None))
+    z_axis = ("model" if msh.model_extent(mesh) > 1 and z_spec[0] is not None
+              else None)
+    body = functools.partial(_round_body, axis_name="model",
+                             m_pad=proposal.tree.W.shape[0], z_axis=z_axis)
+    specs = (tree_shard_specs(proposal.tree, mesh), sp_spec, P(),
+             None if live is None else sp_spec, P())
+    f = jax.shard_map(body, mesh=mesh, in_specs=specs,
+                      out_specs=(P(None),) * 3, check_vma=False)
+    return f(*parts, keys)
 
 
 def auto_n_spec(sampler: NDPPSampler, max_spec: int = 64) -> int:
@@ -430,7 +389,7 @@ def sample_batched_many(
     request keys) or an (n, 2) array of per-request keys.  ``n_spec=None``
     auto-sizes the first round to ~E[#trials] (``auto_n_spec``).
     ``mesh``: run every round item-sharded across the mesh "model" axis
-    (``_spec_round_sharded``); pass an already-placed ``shard_sampler``
+    (``_spec_round_fused(mesh=)``); pass an already-placed ``shard_sampler``
     output to avoid re-sharding per round.  Draws, trial counts, and
     accept flags are bit-identical to the single-device path.
     ``observer``: duck-typed telemetry sink (e.g.
@@ -451,10 +410,7 @@ def sample_batched_many(
         # dispatch (lax.while_loop over rounds) with no per-round host sync
         return _drive_rounds_fused(sampler, jnp.asarray(req_keys),
                                    n_spec=n_spec, max_trials=max_trials)
-    round_fn = (
-        (lambda keys: _spec_round(sampler, keys)) if mesh is None
-        else (lambda keys: _spec_round_sharded(sampler, keys, mesh)))
-    return drive_rounds(round_fn, req_keys, sampler.tree.R, n_spec=n_spec,
+    return drive_rounds(sampler, req_keys, n_spec=n_spec, mesh=mesh,
                         max_trials=max_trials, grow=grow, max_spec=max_spec,
                         observer=observer)
 
@@ -494,7 +450,7 @@ def _drive_rounds_fused(
         spent, items, mask, trials, accepted = carry
         starts = jnp.broadcast_to(spent.astype(jnp.uint32), (n,))
         keys = _fanout_traced(req_keys, starts, offsets)
-        it, mk, ac = _spec_round_impl(sampler, keys)
+        it, mk, ac = _round_body(sampler.tree, sampler.sp, None, None, keys)
         it = it.reshape(n, n_spec, r)
         mk = mk.reshape(n, n_spec, r)
         ac = ac.reshape(n, n_spec)
@@ -530,18 +486,19 @@ def _drive_rounds_fused(
 
 
 def drive_rounds(
-    round_fn, req_keys: jax.Array, r: int, *, n_spec: int,
+    proposal, req_keys: jax.Array, *, n_spec: int,
+    live: Optional[SpectralNDPP] = None, mesh: Optional[Mesh] = None,
     max_trials: int = 1000, grow: int = 2, max_spec: int = 64,
     observer=None,
 ) -> RejectionSample:
-    """Speculative-round driver shared by the static sampler and the
-    dynamic-catalog sampler (``core.dynamic.sample_state_many``).
+    """Host speculative-round driver shared by the static sampler and the
+    dynamic-catalog sampler (``core.dynamic.sample_dynamic_many``).
 
-    ``round_fn(keys)`` scores one proposal per (P, 2) key and returns
-    (items, mask, accept); this loop owns the retire-first-acceptance /
-    double-on-miss scheduling around it.  Proposal t of request i is always
-    keyed ``fold_in(req_keys[i], t)``, so results are independent of the
-    batching schedule and of which round function runs the proposals.
+    Each round is one ``_spec_round_fused(proposal, keys, spent, live=,
+    mesh=)`` dispatch over the still-pending requests; this loop owns the
+    retire-first-acceptance / double-on-miss scheduling around it.
+    Proposal t of request i is always keyed ``fold_in(req_keys[i], t)``,
+    so results are independent of the batching schedule.
 
     ``observer``: optional duck-typed telemetry sink — after each round's
     designed ``device_get`` it receives ``on_round(n_active=, n_spec=,
@@ -551,11 +508,12 @@ def drive_rounds(
     no sync points and cannot perturb the draws).  An observer may also
     provide a ``phase(name)`` context-manager hook (profiler scopes: the
     round dispatch and the harvest sync get named ranges —
-    ``repro.obs.prof.phases``).  The traced round bodies stay free of
+    ``repro.obs.prof.phases``).  The traced round body stays free of
     telemetry; pass e.g. ``repro.obs.RegistryObserver``.
     """
     phase = getattr(observer, "phase", None) or (lambda name: _NO_PHASE)
     n = req_keys.shape[0]
+    r = proposal.tree.R
     items_out = np.full((n, r), -1, np.int32)
     mask_out = np.zeros((n, r), bool)
     trials_out = np.zeros((n,), np.int32)
@@ -574,18 +532,13 @@ def drive_rounds(
         usable = min(cur, max_trials - spent)
         n_act = int(active.size)
         n_pad = 1 << max(0, n_act - 1).bit_length()
-        act_keys = jnp.asarray(req_keys_h[active])
-        if n_pad > n_act:          # pad with repeats; results are discarded
-            act_keys = jnp.concatenate(
-                [act_keys, jnp.broadcast_to(act_keys[:1], (n_pad - n_act, 2))]
-            )
+        # pad with repeats of the last key; their results are discarded
+        act_keys = req_keys_h[np.pad(active, (0, n_pad - n_act),
+                                     mode="edge")]
         with phase("round_dispatch"):
-            keys = _fanout_keys(
-                act_keys,
-                jnp.full((n_pad,), spent, jnp.uint32),
-                jnp.arange(cur, dtype=jnp.uint32),
-            )
-            items, mask, accept = round_fn(keys)
+            items, mask, accept = _spec_round_fused(
+                proposal, act_keys, np.full((n_pad,), spent, np.uint32),
+                n_spec=cur, live=live, mesh=mesh)
         # the one designed device→host sync per round (the fused
         # ``_drive_rounds_fused`` driver removes it on the default path);
         # explicit so transfer guards see it as intentional

@@ -28,12 +28,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.kernels.bilinear import ops as bilinear_ops
 from repro.kernels.spec_round import ops as spec_round_ops
+from repro.kernels.spec_round.ref import child_scores, descend_ref
 from repro.kernels.tree_sum import ops as tree_sum_ops
 
 from .types import SpectralNDPP
 
-# levels with at most this many nodes are replicated on every shard and
-# scored with the stacked-matmul shallow path (plain and sharded alike);
+# levels with at most this many nodes are replicated on every shard;
 # deeper levels shard their node axis across the mesh "model" axis
 _SHALLOW_MAX = 32
 
@@ -268,20 +268,6 @@ def dual_q0(u: jax.Array, lam: jax.Array, e_masks: jax.Array,
     return jnp.einsum("ik,nk,jk->nij", u, w, u)
 
 
-def _descend(tree: SampleTree, q: jax.Array, u: jax.Array) -> jax.Array:
-    """One root-to-block traversal.  Returns the chosen block index."""
-    idx = jnp.asarray(0, jnp.int32)
-    for lvl in range(1, tree.depth + 1):
-        nodes = tree.levels[lvl]
-        left = nodes[2 * idx]
-        parent = tree.levels[lvl - 1][idx]
-        p_left = jnp.vdot(q, left)
-        p_all = jnp.vdot(q, parent)
-        go_left = u[lvl - 1] * jnp.maximum(p_all, 1e-30) <= jnp.maximum(p_left, 0.0)
-        idx = 2 * idx + jnp.where(go_left, 0, 1)
-    return idx
-
-
 def sample_elementary(
     tree: SampleTree, e_mask: jax.Array, key: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
@@ -306,7 +292,7 @@ def sample_elementary(
         active = t < n_e
         kd, kl = jax.random.split(keys[t])
         us = jax.random.uniform(kd, (tree.depth,), dtype=tree.W.dtype)
-        blk = _descend(tree, q, us)
+        blk = descend_ref(tree.levels, q[None], us[None])[0]
         w_blk = jax.lax.dynamic_slice_in_dim(tree.W, blk * tree.block, tree.block)
         scores = jnp.maximum(_leaf_scores(w_blk, q), 0.0)
         j_local = jax.random.categorical(kl, jnp.log(scores + 1e-30))
@@ -354,71 +340,33 @@ def _gather_row(W: jax.Array, j: jax.Array,
 
 
 def _descend_batch(
-    tree: SampleTree, q: jax.Array, us: jax.Array, *,
-    axis_name: Optional[str] = None,
+    tree: SampleTree, q: jax.Array, us: jax.Array, *, axis_name: str,
 ) -> jax.Array:
-    """Root-to-block traversal for N proposals in lockstep.
+    """``descend_ref`` inside a ``shard_map``: N proposals walk the tree in
+    lockstep.  q: (N, R, R) projectors; us: (N, depth) uniforms.  Returns
+    the chosen block index per proposal (N,).
 
-    q: (N, R, R) per-proposal conditioning projectors; us: (N, depth)
-    uniforms.  Returns the chosen block index per proposal (N,).
-
-    The parent's mass is carried down (p_child = p_left or p_all - p_left)
-    instead of re-gathering the parent node, so each level costs one
-    (N, R, R) gather + one inner product instead of two of each — the
-    gathers dominate HBM traffic at batch size N.  Shallow levels (few
-    distinct nodes shared by all N lanes) are scored against *every* node
-    with one stacked (nodes, R^2) x (R^2, N) matmul instead of per-lane
-    matrix gathers; deep levels (nodes >~ lanes) keep the gather.
-
-    With ``axis_name`` set this runs *inside* a ``shard_map``: shallow
-    levels (global node count <= _SHALLOW_MAX) are replicated on every
-    shard and use the identical stacked matmul; a deep level whose local
-    node count is smaller than its global 2^lvl is sharded, and the
-    left-child score is computed by its owner shard and psum'd (every other
-    shard contributes exact zeros) — so the sharded descent visits exactly
-    the same block as the single-device descent, bit for bit.
+    A replicated level (``_SHALLOW_MAX``) is scored as on one device.  A
+    level whose local node count is smaller than its global 2^lvl is
+    sharded: the shard that owns a lane's node pair (siblings never
+    straddle shards) scores both children, and a psum to which every other
+    shard contributes exact zeros hands the scores to all — so the sharded
+    descent visits exactly the same block as ``descend_ref``, bit for bit.
     """
-    n = q.shape[0]
-    r = q.shape[-1]
-    idx = jnp.zeros((n,), jnp.int32)
-    depth = tree.depth
-    # levels whose whole node set is cheaper to score than to gather per
-    # lane — classified by *global* node count 2^lvl so the plain and
-    # sharded paths agree on the split
-    shallow = [lvl for lvl in range(1, depth + 1) if (1 << lvl) <= _SHALLOW_MAX]
-    p_all = jnp.einsum("ij,nij->n", tree.levels[0][0], q)
-    offs = {}
-    if shallow:
-        stacked = jnp.concatenate(
-            [tree.levels[lvl].reshape(-1, r * r) for lvl in shallow]
-        )                                            # (sum 2^lvl, R^2)
-        all_scores = stacked @ q.reshape(n, r * r).T  # (sum 2^lvl, N)
-        off = 0
-        for lvl in shallow:
-            offs[lvl] = off
-            off += tree.levels[lvl].shape[0]
-    shard = None if axis_name is None else jax.lax.axis_index(axis_name)
-    for lvl in range(1, depth + 1):
+    shard = jax.lax.axis_index(axis_name)
+
+    def scores(lvl, left):
         nodes = tree.levels[lvl]
-        if lvl in offs:
-            s_l = all_scores[offs[lvl]:offs[lvl] + nodes.shape[0]]
-            p_left = jnp.take_along_axis(s_l.T, (2 * idx)[:, None], axis=1)[:, 0]
-        elif axis_name is None or nodes.shape[0] == (1 << lvl):
-            left = nodes[2 * idx]                   # (N, R, R) gather
-            p_left = jnp.einsum("nij,nij->n", q, left)
-        else:                                       # sharded level
-            n_loc = nodes.shape[0]
-            base = shard * n_loc
-            g = 2 * idx
-            own = (g >= base) & (g < base + n_loc)
-            left = nodes[jnp.clip(g - base, 0, n_loc - 1)]
-            p_left = jax.lax.psum(
-                jnp.where(own, jnp.einsum("nij,nij->n", q, left), 0.0),
-                axis_name)
-        go_left = us[:, lvl - 1] * jnp.maximum(p_all, 1e-30) <= jnp.maximum(p_left, 0.0)
-        idx = 2 * idx + jnp.where(go_left, 0, 1)
-        p_all = jnp.maximum(jnp.where(go_left, p_left, p_all - p_left), 0.0)
-    return idx
+        n_loc = nodes.shape[0]
+        if n_loc == 1 << lvl:
+            return child_scores(nodes, q, left)
+        base = shard * n_loc
+        own = (left >= base) & (left < base + n_loc)
+        pair = child_scores(nodes, q, jnp.clip(left - base, 0, n_loc - 2))
+        return jax.lax.psum(tuple(jnp.where(own, p, 0.0) for p in pair),
+                            axis_name)
+
+    return descend_ref(tree.levels, q, us, scores=scores)
 
 
 def sample_elementary_batch(
@@ -609,52 +557,22 @@ def shard_spectral(sp: SpectralNDPP, mesh: Mesh) -> SpectralNDPP:
 @functools.partial(jax.jit, static_argnames=("mesh",))
 def sample_proposal_dpp_batch_sharded(
     tree: SampleTree, keys: jax.Array, mesh: Mesh,
-    dual_u: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """``sample_proposal_dpp_batch`` with the tree sharded over the mesh
     "model" axis: deep-level descent and leaf scoring run on the shard that
     owns the nodes/rows, cross-shard combination is a psum of exact zeros —
     draws are bit-identical to the single-device sampler for any shard
-    count.  ``dual_u`` (replicated) switches to the dual-tree projectors
-    exactly as in the plain entry point."""
-    specs = tree_shard_specs(tree, mesh)
+    count."""
     m_pad = tree.W.shape[0]
 
-    if dual_u is None:
-        def inner(tree_loc, keys):
-            return sample_proposal_dpp_batch(
-                tree_loc, keys, axis_name="model", m_pad_global=m_pad)
-
-        f = jax.shard_map(inner, mesh=mesh, in_specs=(specs, P(None)),
-                          out_specs=(P(None), P(None)), check_vma=False)
-        return f(tree, keys)
-
-    def inner(tree_loc, keys, u):
+    def inner(tree_loc, keys):
         return sample_proposal_dpp_batch(
-            tree_loc, keys, axis_name="model", m_pad_global=m_pad, dual_u=u)
+            tree_loc, keys, axis_name="model", m_pad_global=m_pad)
 
     f = jax.shard_map(inner, mesh=mesh,
-                      in_specs=(specs, P(None), P(None, None)),
+                      in_specs=(tree_shard_specs(tree, mesh), P(None)),
                       out_specs=(P(None), P(None)), check_vma=False)
-    return f(tree, keys, dual_u)
-
-
-@functools.partial(jax.jit, static_argnames=("mesh",))
-def sample_elementary_batch_sharded(
-    tree: SampleTree, e_masks: jax.Array, keys: jax.Array, mesh: Mesh
-) -> Tuple[jax.Array, jax.Array]:
-    """``sample_elementary_batch`` through a mesh-sharded tree (see
-    ``sample_proposal_dpp_batch_sharded``)."""
-    specs = tree_shard_specs(tree, mesh)
-    m_pad = tree.W.shape[0]
-
-    def inner(tree_loc, e_masks, keys):
-        return sample_elementary_batch(
-            tree_loc, e_masks, keys, axis_name="model", m_pad_global=m_pad)
-
-    f = jax.shard_map(inner, mesh=mesh, in_specs=(specs, P(None), P(None)),
-                      out_specs=(P(None), P(None)), check_vma=False)
-    return f(tree, e_masks, keys)
+    return f(tree, keys)
 
 
 def sample_elementary_dense(

@@ -4,8 +4,7 @@ Dispatches the rejection hot path's per-round tree traversal: the Pallas
 kernel on TPU (or under interpret), the pure-jnp oracle everywhere else.
 The oracle *is* the committed CPU arithmetic — ``core.tree`` routes its
 unsharded descent through here, and the golden-file suite pins its draws
-bit-for-bit — so the ref path must not be "equivalent", it must be
-identical (see ref.py).
+bit-for-bit (see ref.py).
 
 The kernel reads the levels below the root in a flat (S, 128) node
 layout.  ``descent_operands`` builds it once per round, outside the
@@ -15,11 +14,9 @@ lane groups from the node slab size and the batch: groups of ``group``
 lanes take turns, so the node-pair DMAs of the other groups of a grid
 step stay in flight while one group is scored.
 
-The kernel scores both children of every visited node and carries no
-mass down, where the oracle carries ``p_all - p_left``: they part only
-on a decision that the oracle's carried rounding moves across its
-threshold (``ref.descend_pair_ref`` is the kernel's rule in jnp, for
-tests).
+The kernel and the oracle take the same rule (both children scored, no
+mass carried); they can part only where their float32 sums of products,
+taken in another order, fall on two sides of a decision's threshold.
 """
 from __future__ import annotations
 

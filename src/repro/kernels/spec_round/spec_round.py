@@ -5,8 +5,9 @@ its conditioning projector and goes left iff
 ``u * max(p_left + p_right, 1e-30) <= max(p_left, 0)``.  Both sides of a
 decision are scored at the node's own size: no mass is carried from one
 level to the next, so no level inherits the rounding of the levels above
-it (a mass carried down by subtraction, ``p_all - p_left``, holds the
-rounding of the root's score, about 2^level times the node's own size).
+it (a parent's mass less its left child's score holds the rounding of
+the root's score, about 2^level times the node's own size).  The rule is
+``ref.descend_ref``'s, the oracle of every other path.
 
 Memory.  Level 1 (the root's two children, the same pair for every lane)
 is one VMEM block, read once per call.  Levels 2..depth stay in HBM

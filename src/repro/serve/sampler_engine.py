@@ -52,15 +52,10 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro.core import mcmc as mcmc_core
-from repro.core.dynamic import (
-    _spec_round_dual_fused,
-    _spec_round_dual_fused_sharded,
-    auto_n_spec_dynamic,
-)
+from repro.core.dynamic import auto_n_spec_dynamic
 from repro.core.rejection import (
     NDPPSampler,
     _spec_round_fused,
-    _spec_round_fused_sharded,
     auto_n_spec,
     shard_sampler,
 )
@@ -685,33 +680,18 @@ class SamplerEngine:
                 ((self.slot_pin[ss[0]], ss) for ss in by_pin.values()),
                 key=lambda g: g[0].version)
         for pin, slots in slot_groups:
+            # a pinned version proposes from its snapshot and scores its
+            # live kernel; a static sampler scores its own
+            kw = {} if self.mesh is None else {"mesh": self.mesh}
+            if pin is not None:
+                kw["live"] = pin.sp
             # exactly one dispatch per speculative round: fan-out, round
             # body, and accept test ride in the same jit
             with self._phase(prof_phases.ROUND_DISPATCH):
-                if pin is None:
-                    items, mask, accept = (
-                        self._acct.call(
-                            "_spec_round_fused", _spec_round_fused,
-                            self.sampler, self.slot_key, trials_host,
-                            n_spec=self.n_spec)
-                        if self.mesh is None
-                        else self._acct.call(
-                            "_spec_round_fused_sharded",
-                            _spec_round_fused_sharded,
-                            self.sampler, self.slot_key, trials_host,
-                            self.mesh, n_spec=self.n_spec))
-                else:
-                    items, mask, accept = (
-                        self._acct.call(
-                            "_spec_round_dual_fused", _spec_round_dual_fused,
-                            pin.proposal, pin.sp, self.slot_key, trials_host,
-                            n_spec=self.n_spec)
-                        if self.mesh is None
-                        else self._acct.call(
-                            "_spec_round_dual_fused_sharded",
-                            _spec_round_dual_fused_sharded,
-                            pin.proposal, pin.sp, self.slot_key, trials_host,
-                            self.mesh, n_spec=self.n_spec))
+                items, mask, accept = self._acct.call(
+                    "_spec_round_fused", _spec_round_fused,
+                    self.sampler if pin is None else pin.proposal,
+                    self.slot_key, trials_host, n_spec=self.n_spec, **kw)
             self._harvest(slots, items, mask, accept)
         return True
 

@@ -14,6 +14,12 @@ diff of ``tests/golden/*.json``, never a silent drift.
 Only discrete outputs belong in a golden payload (ints and booleans):
 they are stable under last-bit float jitter across BLAS builds, while raw
 log-probabilities would not be.
+
+The committed files were generated under JAX 0.9.0 with its default
+``jax_threefry_partitionable=True`` (the default since JAX 0.5.0).  That
+flag chooses how threefry keys are split and bits are drawn, so it moves
+every draw of every backend at once: when all files differ together,
+compare the JAX version and that flag before suspecting the samplers.
 """
 import json
 import pathlib

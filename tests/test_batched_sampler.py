@@ -127,24 +127,25 @@ def test_fused_driver_matches_python_driver(sampler):
                           np.asarray(host.accepted))
 
 
-def test_drive_rounds_truncation_keeps_pow2_shapes(sampler):
+def test_drive_rounds_truncation_keeps_pow2_shapes(sampler, monkeypatch):
     """Budget truncation masks lanes instead of reshaping: every round
     dispatch keeps its power-of-two width even when the remaining budget
     is smaller than the doubled round (no fresh jit cache entry near
     exhaustion), and the draws still match the fused driver."""
-    from repro.core.rejection import _spec_round, drive_rounds
+    from repro.core import rejection
 
     widths = []
+    orig = rejection._spec_round_fused
 
-    def round_fn(keys):
-        widths.append(int(keys.shape[0]))
-        return _spec_round(sampler, keys)
+    def recorded(proposal, keys, trials, *, n_spec, **kw):
+        widths.append(int(keys.shape[0]) * n_spec)
+        return orig(proposal, keys, trials, n_spec=n_spec, **kw)
 
+    monkeypatch.setattr(rejection, "_spec_round_fused", recorded)
     req = jax.random.split(jax.random.PRNGKey(5), 6)
     # max_trials=10: after rounds of 4 the doubled round of 8 has only 6
     # in-budget lanes — the dispatch must still be 8 wide
-    res = drive_rounds(round_fn, req, sampler.tree.R, n_spec=4,
-                       max_trials=10)
+    res = rejection.drive_rounds(sampler, req, n_spec=4, max_trials=10)
     assert widths, "no rounds dispatched"
     assert len(widths) >= 2, widths   # the truncated round must occur
     for w in widths:

@@ -12,7 +12,7 @@ from repro.kernels.mcmc_score import ops as mops
 from repro.kernels.mcmc_score.ref import score_all_ref
 from repro.kernels.slogdet import ops as slops
 from repro.kernels.spec_round import ops as spops
-from repro.kernels.spec_round.ref import descend_pair_ref, descend_ref
+from repro.kernels.spec_round.ref import descend_ref
 from repro.kernels.ssd import ops as sops
 from repro.kernels.ssd.ref import ssd_ref
 from repro.kernels.tree_sum import ops as tops
@@ -89,8 +89,8 @@ def test_gathered_block_grams(rng, m, blk, r, nb):
 
 def _random_tree_levels(rng, depth, r):
     """A mass-consistent proposal tree: random PSD leaf nodes, parents the
-    sum of their children — so the descent's p_left / p_all - p_left
-    carry-down walks real masses, not arbitrary numbers."""
+    sum of their children — so the descent's sibling scores are real
+    masses, not arbitrary numbers."""
     leaves = rng.normal(size=(1 << depth, r, r)).astype(np.float32)
     nodes = jnp.asarray(np.einsum("nik,njk->nij", leaves, leaves))
     levels = [nodes]
@@ -106,11 +106,9 @@ def _random_tree_levels(rng, depth, r):
                                              (1, 4, 8, 5), (1, 2, 130, 20),
                                              (6, 2, 100, 200)])
 def test_spec_round_descend_score(depth, block, r, n):
-    """HBM-resident descent kernel (interpret mode) vs the jnp oracles:
-    block choices identical to the kernel's own rule
-    (``descend_pair_ref``) and to the carried oracle ``descend_ref``, then
-    matching raw leaf scores of the chosen blocks.  Spans shallow-only
-    trees (depth <= 5 under _SHALLOW_MAX=32), deep per-lane gathers,
+    """HBM-resident descent kernel (interpret mode) vs the jnp oracle
+    ``descend_ref``: identical block choices, then matching raw leaf
+    scores of the chosen blocks.  Spans shallow trees and deep ones,
     depth 1, lane counts off the kernel's group and step multiples,
     several lane groups taking turns within a grid step (70, 200),
     several grid steps at the benchmark's R = 100, and R past one
@@ -127,8 +125,6 @@ def test_spec_round_descend_score(depth, block, r, n):
     assert flat[-1].shape[1:] == (-(-r * r // 1024) * 8, 128)
     blk = spops.descend(levels, flat, q, us, force_interpret=True)
     blk_ref = descend_ref(levels, q, us)
-    np.testing.assert_array_equal(np.asarray(blk),
-                                  np.asarray(descend_pair_ref(levels, q, us)))
     np.testing.assert_array_equal(np.asarray(blk), np.asarray(blk_ref))
     rows = blk_ref[:, None] * block + jnp.arange(block)[None, :]
     sc = bops.bilinear_batched(w[rows], q, force_interpret=True)
@@ -161,13 +157,14 @@ def test_spec_round_descent_deep_low_rank_matches_float64():
     """Late in a draw the projector is low rank and orthogonal to where
     the nodes hold most of their weight, so <Q, node> cancels: here rank 2
     against rows whose variance is 10^4 times larger along one direction
-    the projector removes, over a depth-12 tree.  The kernel, scoring both
-    children of every node, makes every decision a float64 descent makes.
-    The carried oracle (``descend_ref``: the parent's mass carried down as
-    p_all - p_left) keeps the rounding of the root's score in the mass, so
-    on the float64 path its ratios stray by about 2^level times the pair
-    rule's, and it chooses other blocks.  Uniforms lie in [0.6, 1), so
-    the paths turn right, where the carried mass is subtracted."""
+    the projector removes, over a depth-12 tree.  The kernel and the
+    oracle ``descend_ref``, scoring both children of every node, make
+    every decision a float64 descent makes.  The negative control carries
+    the parent's mass down as p_all - p_left, as the descent once did: it
+    keeps the rounding of the root's score in the mass, so on the float64
+    path its ratios stray by about 2^level times the pair rule's, and it
+    chooses other blocks.  Uniforms lie in [0.6, 1), so the paths turn
+    right, where the carried mass is subtracted."""
     depth, block, r, n = 12, 4, 8, 64
     rng = np.random.default_rng(12)
     f = rng.normal(size=r)
@@ -221,17 +218,18 @@ def test_spec_round_descent_deep_low_rank_matches_float64():
     assert stray_pair.max() < 1e-3          # 9.8e-5 on this input
     assert stray_carried[-1] > 0.1          # 0.44 on this input
     assert (stray_carried[-4:] > 100 * stray_pair[-4:]).all()
-    assert (np.asarray(descend_ref(lv32, q, jnp.asarray(us))) != idx).any()
+    np.testing.assert_array_equal(
+        np.asarray(descend_ref(lv32, q, jnp.asarray(us))), idx)
 
-
-def test_spec_round_shallow_max_matches_tree():
-    """The oracle's shallow/deep level classifier must agree with
-    core.tree's, or the fused path and the sharded descent would walk the
-    same tree with different stacked-matmul layouts."""
-    from repro.core import tree as core_tree
-    from repro.kernels.spec_round import ref as spref
-
-    assert spref._SHALLOW_MAX == core_tree._SHALLOW_MAX
+    blk_c = jnp.zeros(n, jnp.int32)      # the carried rule picks others
+    p_all = jnp.einsum("ij,nij->n", lv32[0][0], q)
+    for lvl in range(1, depth + 1):
+        p_left = jnp.einsum("nij,nij->n", q, lv32[lvl][2 * blk_c])
+        go_left = us[:, lvl - 1] * jnp.maximum(p_all, 1e-30) \
+            <= jnp.maximum(p_left, 0.0)
+        blk_c = 2 * blk_c + jnp.where(go_left, 0, 1)
+        p_all = jnp.maximum(jnp.where(go_left, p_left, p_all - p_left), 0.0)
+    assert (np.asarray(blk_c) != idx).any()
 
 
 #: subsets per size in the acceptance batch, and the sizes it cycles over

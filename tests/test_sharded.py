@@ -16,6 +16,7 @@ chain starts, plus
 distribution-equality of the sharded rejection sampler against the
 enumerated target (the ``tests/_exactness.py`` chi-square bar).
 """
+import json
 import os
 import subprocess
 import sys
@@ -256,3 +257,130 @@ def test_sharded_two_simulated_devices():
     )
     assert proc.returncode == 0, (proc.stdout, proc.stderr)
     assert "SHARDED-2DEV-OK" in proc.stdout, proc.stdout
+
+
+_ONE_RULE_SCRIPT = textwrap.dedent("""
+    import functools, json
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    assert len(jax.devices()) == 2, jax.devices()
+    mesh = Mesh(np.asarray(jax.devices()), ("model",))
+
+    from repro.core import preprocess, shard_sampler, shard_tree
+    from repro.core.dynamic import build_dual_proposal
+    from repro.core.rejection import _spec_round_fused
+    from repro.core.tree import (_descend_batch, construct_tree,
+                                 shard_spectral, tree_shard_specs)
+    from repro.kernels.spec_round.ref import descend_ref
+
+    out = {}
+
+    def descent_case(name, w, q, us, block):
+        tree = construct_tree(jnp.zeros(w.shape[1]), jnp.asarray(w), block)
+        specs = tree_shard_specs(tree, mesh)
+        assert any(s != P(None, None, None) for s in specs.levels), name
+        one = jax.jit(descend_ref)(tree.levels, q, us)
+        f = jax.shard_map(
+            functools.partial(_descend_batch, axis_name="model"), mesh=mesh,
+            in_specs=(specs, P(), P()), out_specs=P(), check_vma=False)
+        two = jax.jit(f)(shard_tree(tree, mesh), q, us)
+        out[name] = bool(np.array_equal(np.asarray(one), np.asarray(two)))
+
+    rng = np.random.default_rng(6)
+    for depth in (6, 8):                 # levels 6.. hold > 32 nodes
+        r, n = 8, 64
+        w = rng.normal(size=((1 << depth) * 4, r)).astype(np.float32)
+        qh = rng.normal(size=(n, r, r)).astype(np.float32)
+        q = jnp.asarray(np.einsum("nik,njk->nij", qh, qh) / r)
+        us = jnp.asarray(rng.uniform(size=(n, depth)), jnp.float32)
+        descent_case(f"descent-depth{depth}", w, q, us, 4)
+
+    # depth 12, low rank: a rank-2 projector orthogonal to the direction
+    # that holds 10^4 of the rows' variance (test_kernels' deep case)
+    depth, r, n = 12, 8, 64
+    rng = np.random.default_rng(12)
+    f = rng.normal(size=r)
+    f /= np.linalg.norm(f)
+    w = (100.0 * rng.normal(size=((1 << depth) * 4, 1)) * f
+         + rng.normal(size=((1 << depth) * 4, r))).astype(np.float32)
+    proj = []
+    for _ in range(n):
+        x = rng.normal(size=(r, 2))
+        x -= np.outer(f, f @ x)
+        basis = np.linalg.qr(x)[0]
+        proj.append(basis @ basis.T)
+    q = jnp.asarray(np.stack(proj), jnp.float32)
+    us = jnp.asarray(rng.uniform(0.6, 1.0, size=(n, depth)), jnp.float32)
+    descent_case("descent-deep-low-rank", w, q, us, 4)
+
+    # one round on a mesh against one device: 1024 items, block 4, so
+    # levels 6-8 and W are split across the two devices
+    rng = np.random.default_rng(2024)
+    v = jnp.asarray(rng.normal(size=(1024, 4)) * 0.1, jnp.float32)
+    b = jnp.asarray(rng.normal(size=(1024, 4)) * 0.1, jnp.float32)
+    d = jnp.asarray(rng.normal(size=(4, 4)), jnp.float32)
+    sampler = preprocess(v, b, d, block=4)
+    keys = np.asarray(jax.random.split(jax.random.PRNGKey(0), 8))
+    trials = np.arange(8, dtype=np.uint32) * 3
+
+    def round_case(name, one, two, one_live=None, two_live=None):
+        a = _spec_round_fused(one, keys, trials, n_spec=8, live=one_live)
+        c = _spec_round_fused(two, keys, trials, n_spec=8, live=two_live,
+                              mesh=mesh)
+        out[name] = all(np.array_equal(np.asarray(x), np.asarray(y))
+                        for x, y in zip(a, c))
+
+    sh = shard_sampler(sampler, mesh)
+    assert sh.tree.W.addressable_shards[0].data.shape[0] * 2 \\
+        == sh.tree.W.shape[0]
+    round_case("round-static", sampler, sh)
+    sp = sampler.sp
+    round_case("round-dual", build_dual_proposal(sp, block=4),
+               build_dual_proposal(sp, block=4, mesh=mesh),
+               sp, shard_spectral(sp, mesh))
+    print("ONE-RULE-JSON:" + json.dumps(out))
+""")
+
+
+@pytest.fixture(scope="module")
+def one_rule_two_devices():
+    """Each case of the 2-simulated-device script below: True where the
+    mesh result equals the one-device result bit for bit."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env.update(
+        XLA_FLAGS="--xla_force_host_platform_device_count=2",
+        JAX_PLATFORMS="cpu",
+        PYTHONPATH=os.pathsep.join(
+            [os.path.join(root, "src")]
+            + ([env_p] if (env_p := env.get("PYTHONPATH")) else [])),
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", _ONE_RULE_SCRIPT], env=env, cwd=root,
+        capture_output=True, text=True, timeout=900,
+    )
+    assert proc.returncode == 0, (proc.stdout, proc.stderr)
+    line = next(ln for ln in proc.stdout.splitlines()
+                if ln.startswith("ONE-RULE-JSON:"))
+    return json.loads(line[len("ONE-RULE-JSON:"):])
+
+
+@pytest.mark.parametrize("case", ["descent-depth6", "descent-depth8",
+                                  "descent-deep-low-rank"])
+def test_descend_batch_two_devices_equals_descend_ref(one_rule_two_devices,
+                                                      case):
+    """The sharded descent (``core.tree._descend_batch``, levels past
+    ``_SHALLOW_MAX`` split over 2 devices) walks every lane to the block
+    the one-device oracle ``descend_ref`` picks, bit for bit, including on
+    the depth-12 tree whose projector cancels 10^4 to 1."""
+    assert one_rule_two_devices[case]
+
+
+@pytest.mark.parametrize("case", ["round-static", "round-dual"])
+def test_spec_round_fused_mesh_equals_one_device(one_rule_two_devices, case):
+    """``_spec_round_fused(mesh=)`` over 2 devices returns the one-device
+    round's items, masks and accept flags bit for bit: for a static
+    sampler, and for an unmutated ``DualProposal`` scored against its live
+    kernel (``live=``)."""
+    assert one_rule_two_devices[case]

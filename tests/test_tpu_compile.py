@@ -31,11 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import mcmc as mcmc_core
-from repro.core.rejection import (
-    NDPPSampler,
-    _spec_round_fused,
-    _spec_round_fused_sharded,
-)
+from repro.core.rejection import NDPPSampler, _spec_round_fused
 from repro.core.tree import construct_tree, proposal_eigens, tree_shard_specs
 from repro.core.types import SpectralNDPP
 from repro.kernels.bilinear import ops as bilinear_ops
@@ -220,9 +216,10 @@ def test_sharded_paths_compile(v5e_devices, monkeypatch):
         shapes, specs, is_leaf=lambda x: isinstance(x, P))
     rep = NamedSharding(mesh, P())
     slots = 8
-    tick = _spec_round_fused_sharded.lower(
+    tick = _spec_round_fused.lower(
         sampler, _shape(rep, (slots, 2), jnp.uint32),
-        _shape(rep, (slots,), jnp.uint32), mesh, n_spec=w.lanes // slots,
+        _shape(rep, (slots,), jnp.uint32), n_spec=w.lanes // slots,
+        mesh=mesh,
     ).compile()
     assert _kernels(tick) >= 1
     states = jax.tree.map(
